@@ -213,7 +213,6 @@ def _phase_breakdown(plan, mesh=None, iters=8):
 
     Returns {phase: us_per_sync}; the caller amortises by the plan's
     sync interval.  SKIP rungs and empty buckets contribute nothing."""
-    from repro import compat
     from repro.codecs.base import BLOCK, pack_payload
     from repro.kernels import ops as kops
     from jax.sharding import PartitionSpec as P
@@ -258,9 +257,8 @@ def _phase_breakdown(plan, mesh=None, iters=8):
 
                 def exch(w):
                     return jax.lax.psum(w, "pod")
-            smapped = compat.shard_map(
-                exch, mesh, in_specs=P(), out_specs=P(),
-                manual_axes=set(mesh.axis_names))
+            smapped = jax.shard_map(exch, mesh=mesh, in_specs=P(),
+                                    out_specs=P(), check_vma=False)
             phases["exchange"] += _time(jax.jit(smapped), wire,
                                         iters=iters)
     return phases

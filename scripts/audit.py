@@ -6,9 +6,11 @@ invariants on the simulated (2,2,2) meshes.
     PYTHONPATH=src python scripts/audit.py --strategy acesync --out AUDIT.json
     PYTHONPATH=src python scripts/audit.py --fail-on-violation   # CI gate
 
-MUST set the host-device override before ANY import touches jax."""
+MUST set the host-device override before ANY import touches jax.  The
+audit is a CPU tool: it pins the CPU backend, so it never takes a chip."""
 import os
 
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
 os.environ.setdefault("XLA_FLAGS",
                       "--xla_force_host_platform_device_count=8")
 os.environ.setdefault("REPRO_FORCE_INTERPRET", "1")

@@ -36,7 +36,10 @@ def run(arch, shape, multi_pod, strategy="acesync", timeout=900):
            "--shape", shape, "--strategy", strategy, "--out", OUT]
     if multi_pod:
         cmd.append("--multi-pod")
-    env = dict(os.environ, PYTHONPATH=os.path.join(ROOT, "src"))
+    # the dry-run is a CPU tool (512 virtual host devices): pin the CPU
+    # backend so no child tries to take an attached chip
+    env = dict(os.environ, PYTHONPATH=os.path.join(ROOT, "src"),
+               JAX_PLATFORMS="cpu")
     t0 = time.time()
     try:
         r = subprocess.run(cmd, cwd=ROOT, env=env, timeout=timeout,

@@ -20,7 +20,9 @@ Invariants checked (all per device, the paper's accounting):
     includes the pod axis unexpectedly.
 
 Sub-threshold all-reduces (metric pmeans of scalar loss/gnorm/divergence)
-are excluded: they are host telemetry, not the sync schedule.
+are excluded: they are host telemetry, not the sync schedule.  Where
+XLA's all-reduce combiner merged such pmeans into a sync all-reduce, only
+that collective's sync-sized operands are counted.
 """
 from __future__ import annotations
 
@@ -99,6 +101,15 @@ def _is_metric(rec: CollectiveRecord) -> bool:
             and rec.payload_bytes < METRIC_BYTES)
 
 
+def _sync_wire(rec: CollectiveRecord) -> float:
+    """Per-device wire bytes of ``rec``'s sync traffic: a combined
+    all-reduce's metric-sized operands are left out."""
+    if rec.opcode != "all-reduce" or len(rec.operand_bytes) < 2:
+        return rec.wire_bytes
+    sync_n = sum(b for b in rec.operand_bytes if b >= METRIC_BYTES)
+    return rec.wire_bytes * sync_n / rec.payload_bytes
+
+
 def audit_collectives(hlo_text: str, ep: planexec.ExecPlan,
                       mesh_shape: Sequence[int],
                       axis_names: Sequence[str], n_pods: int,
@@ -124,8 +135,8 @@ def audit_collectives(hlo_text: str, ep: planexec.ExecPlan,
     mixed = [r for r in slow
              if set(r.axis.split("+")) - {"pod", "edge"}]
 
-    traced_slow = sum(r.wire_bytes * r.trip_mult for r in slow)
-    traced_fast = sum(r.wire_bytes * r.trip_mult for r in fast)
+    traced_slow = sum(_sync_wire(r) * r.trip_mult for r in slow)
+    traced_fast = sum(_sync_wire(r) * r.trip_mult for r in fast)
 
     def _within(traced: float, analytic: float, slack: float) -> bool:
         return analytic - 0.5 <= traced <= analytic + slack + 0.5

@@ -541,6 +541,9 @@ class CollectiveRecord:
     source_target_pairs: Optional[List[List[int]]]
     computation: str
     raw: str
+    #: bytes of each operand (an all-reduce that XLA's combiner merged
+    #: from several reductions carries one operand per reduction)
+    operand_bytes: Tuple[float, ...] = ()
 
 
 def collective_record(op: HloOp, comp: HloComputation,
@@ -556,8 +559,9 @@ def collective_record(op: HloOp, comp: HloComputation,
             groups = pairs
     axis, gsize = classify_axes(groups, mesh_shape, axis_names)
     opc = op.opcode.replace("-start", "")
-    operand_bytes = shapes_bytes([s for v in op.operands
-                                  for s in comp.shape_of.get(v, [])])
+    each = tuple(float(shapes_bytes(comp.shape_of.get(v, [])))
+                 for v in op.operands)
+    operand_bytes = sum(each)
     out_bytes = shapes_bytes(op.shapes)
     if opc == "all-reduce":
         n = float(operand_bytes or out_bytes)
@@ -577,7 +581,8 @@ def collective_record(op: HloOp, comp: HloComputation,
     return CollectiveRecord(
         opcode=opc, axis=axis, group_size=gsize, payload_bytes=n,
         wire_bytes=wire, trip_mult=trip_mult, direction=direction,
-        source_target_pairs=pairs, computation=comp.name, raw=op.raw)
+        source_target_pairs=pairs, computation=comp.name, raw=op.raw,
+        operand_bytes=each)
 
 
 class _CollectiveCollector:

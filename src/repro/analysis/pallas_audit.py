@@ -1,18 +1,19 @@
 """Pallas kernel audit: BlockSpec tiling vs declared operand shapes.
 
-Every registered kernel carries implicit contracts the Mosaic compiler
-only partially enforces (and the interpreter not at all): each BlockSpec
-tile must divide its operand exactly per dimension, and the index map
-must keep every block inside the array for every grid point — an
-off-by-one index map reads out of bounds on hardware while silently
-clamping in interpret mode, which is exactly the class of bug a CPU CI
-cannot catch dynamically.
+Every registered kernel carries implicit contracts the interpreter does
+not enforce: each BlockSpec tile must divide its operand exactly per
+dimension, its last two dims must be multiples of the TPU's (8, 128)
+tile or equal the operand's own dims (Mosaic refuses anything else), and
+the index map must keep every block inside the array for every grid
+point — an off-by-one index map reads out of bounds on hardware while
+silently clamping in interpret mode, which is exactly the class of bug a
+CPU CI cannot catch dynamically.
 
 The audit intercepts ``pl.pallas_call`` (no kernel body ever runs),
 records (grid, specs, operand shapes) for each call, and statically
-checks tiling and index-map bounds.  ``audit_kernels`` drives every
-public kernel entry point in ``repro.kernels`` through the interceptor
-on representative shapes.
+checks divisibility, the TPU tiling rule and index-map bounds.
+``audit_kernels`` drives every public kernel entry point in
+``repro.kernels`` through the interceptor on representative shapes.
 
 Scalar-prefetch index maps (the producer-fused gather path) are
 evaluated with a zero ref: the data-dependent ``perm[i]`` block index is
@@ -34,6 +35,7 @@ from repro.analysis.report import AuditReport
 PASS = "pallas_blockspec"
 
 MAX_GRID_POINTS = 4096      # index-map evaluation cap per call
+TPU_TILE = (8, 128)         # (sublane, lane) tile of a 32-bit vreg
 
 
 @dataclasses.dataclass
@@ -111,6 +113,18 @@ def _spec_geometry(spec) -> Tuple[Optional[tuple], Optional[Callable]]:
     return (tuple(block) if block is not None else None), index_map
 
 
+def tiling_violations(block, shape) -> List[int]:
+    """Dims among the last two whose block size is neither a multiple of
+    :data:`TPU_TILE` nor the operand's full extent (a squeezed ``None``
+    dim counts as 1)."""
+    bad = []
+    for d, tile in zip(range(len(block) - 1, -1, -1), TPU_TILE[::-1]):
+        b = 1 if block[d] is None else int(block[d])
+        if b % tile and b != int(shape[d]):
+            bad.append(d)
+    return sorted(bad)
+
+
 def _grid_points(grid: Tuple[int, ...]):
     total = 1
     for g in grid:
@@ -155,9 +169,21 @@ def check_record(rec: PallasCallRecord, report: AuditReport,
                                 "shape": list(shape),
                                 "bad_dims": bad_dims})
             continue
+        bad_tiles = tiling_violations(block, shape)
+        if bad_tiles:
+            report.add(PASS, where,
+                       f"{kind} block {tuple(block)} breaks the TPU "
+                       f"{TPU_TILE} tiling rule on operand "
+                       f"{tuple(shape)}: its last two dims must be tile "
+                       f"multiples or the operand's own dims",
+                       details={"block": [b for b in block],
+                                "shape": list(shape),
+                                "bad_dims": bad_tiles})
+            continue
         if index_map is None:
             continue
-        nblocks = [int(s) // int(b) for b, s in zip(block, shape)]
+        nblocks = [int(s) // (1 if b is None else int(b))
+                   for b, s in zip(block, shape)]
         extra = ((_ZeroRef(),) if rec.num_scalar_prefetch else ())
         for point in _grid_points(rec.grid):
             try:
@@ -206,83 +232,21 @@ def audit_records(records: Sequence[PallasCallRecord],
 
 
 def _kernel_cases() -> Dict[str, Callable[[], None]]:
-    """One callable per public kernel entry point, on representative
-    shapes.  Each calls the RAW function (``__wrapped__`` under the jit
-    decorator) so the interceptor sees the eager ``pl.pallas_call``."""
-    from repro.kernels import decode, quantize, sign, topk_compress
+    """One callable per public kernel entry point (the
+    :mod:`repro.kernels.cases` table) on small shapes: 4 tiles of rows,
+    a gather of 8 rows out of 12, k = 104.  Each calls the RAW function
+    (``__wrapped__`` under the jit decorator) so the interceptor sees
+    the eager ``pl.pallas_call``."""
+    from repro.kernels.cases import kernel_cases
+    from repro.kernels.topk_compress import ROWS
 
-    R, L = 4 * decode.ROWS, decode.LANES
-    f32, i32 = jnp.float32, jnp.int32
-    g = jnp.zeros((R, L), f32)
-    e = jnp.zeros((R, L), f32)
-    s = jnp.zeros((R, 1), f32)
-    w = jnp.zeros((1, 1), f32)
-    q8 = jnp.zeros((R, L), jnp.int8)
-    p4 = jnp.zeros((R, L // 2), jnp.uint8)
-    p1 = jnp.zeros((R, L // 8), jnp.uint8)
-    acc_i = jnp.zeros((R, L), i32)
-    s_i = jnp.zeros((R, 1), i32)
-    k = 103
-    qk = jnp.zeros((R, k), f32)
-    ik = jnp.zeros((R, k), i32)
-    nb = 11
-    fb = jnp.zeros((nb + 1, L), f32)
-    perm = jnp.zeros((8,), i32)
+    def case_fn(case):
+        fn = getattr(case.fn, "__wrapped__", case.fn)
+        zeros = [jnp.zeros(a.shape, a.dtype) for a in case.args]
+        return lambda: fn(*zeros, interpret=True, **dict(case.kw))
 
-    def raw(fn):
-        return getattr(fn, "__wrapped__", fn)
-
-    return {
-        "quantize_int8_fused":
-            lambda: raw(quantize.quantize_int8_fused)(g, interpret=True),
-        "ef_int4_fused":
-            lambda: raw(quantize.ef_int4_fused)(g, e, gamma=1.0,
-                                                interpret=True),
-        "dequantize_int8":
-            lambda: raw(quantize.dequantize_int8)(q8, s, interpret=True),
-        "quantize_int8_gather":
-            lambda: raw(quantize.quantize_int8_gather)(
-                fb, fb, perm, gamma=1.0, rows=1, interpret=True),
-        "quantize_int8_gather_rows8":
-            lambda: raw(quantize.quantize_int8_gather)(
-                fb, fb, perm, gamma=1.0, rows=8, interpret=True),
-        "ef_int4_gather":
-            lambda: raw(quantize.ef_int4_gather)(
-                fb, fb, perm, gamma=1.0, rows=1, interpret=True),
-        "ef_sign_fused":
-            lambda: raw(sign.ef_sign_fused)(g, e, gamma=1.0,
-                                            interpret=True),
-        "ef_sign_gather":
-            lambda: raw(sign.ef_sign_gather)(
-                fb, fb, perm, gamma=1.0, rows=1, interpret=True),
-        "ef_topk_select":
-            lambda: raw(topk_compress.ef_topk_select)(
-                g, e, gamma=1.0, k=k, interpret=True),
-        "ef_topk_gather":
-            lambda: raw(topk_compress.ef_topk_gather)(
-                fb, fb, perm, gamma=1.0, k=k, rows=1, interpret=True),
-        "dequant_accum_int8_fused":
-            lambda: raw(decode.dequant_accum_int8_fused)(
-                g, q8, s, w, interpret=True),
-        "dequant_accum_int4_fused":
-            lambda: raw(decode.dequant_accum_int4_fused)(
-                g, p4, s, w, interpret=True),
-        "sign_vote_accum_fused":
-            lambda: raw(decode.sign_vote_accum_fused)(
-                g, s, p1, s, w, interpret=True),
-        "topk_scatter_accum_fused":
-            lambda: raw(decode.topk_scatter_accum_fused)(
-                g, qk, ik, s, w, interpret=True),
-        "dequant_accum_int8_fp_fused":
-            lambda: raw(decode.dequant_accum_int8_fp_fused)(
-                acc_i, q8, s, w, bits=16, interpret=True),
-        "dequant_accum_int4_fp_fused":
-            lambda: raw(decode.dequant_accum_int4_fp_fused)(
-                acc_i, p4, s, w, bits=16, interpret=True),
-        "sign_vote_accum_fp_fused":
-            lambda: raw(decode.sign_vote_accum_fp_fused)(
-                acc_i, s_i, p1, s, w, bits=16, interpret=True),
-    }
+    return {c.name: case_fn(c)
+            for c in kernel_cases(rows=4 * ROWS, nb=11, k=104)}
 
 
 def audit_kernels(report: AuditReport) -> dict:

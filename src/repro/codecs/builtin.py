@@ -30,6 +30,7 @@ from repro.kernels import ops
 from repro.kernels.decode import (FIXED_POINT_BITS, fixed_point,
                                   from_fixed_point)
 from repro.kernels.quantize import _int4_body, pack_nibbles, unpack_nibbles
+from repro.kernels.sign import lane_mean
 
 
 @register_codec
@@ -344,7 +345,7 @@ class SignCodec(Codec):
         return 0.25
 
     def encode(self, blocks):
-        scale = jnp.mean(jnp.abs(blocks), axis=1).astype(jnp.float32)
+        scale = lane_mean(jnp.abs(blocks))[:, 0].astype(jnp.float32)
         return {"q": pack_bits(blocks >= 0), "scale": scale}
 
     def decode(self, payload, block: int = BLOCK):

@@ -1,10 +1,11 @@
 """Hierarchical cloud-edge synchronisation (paper eqs. 7-8) on the pod axis.
 
-Execution context: these functions run INSIDE the outer per-pod shard_map
-(manual over "pod"; "data"/"model" auto).  Compression is performed in a
-NESTED shard_map that is manual over "data"/"model" as well, so every device
-compresses exactly its local shard — no resharding — and exchanges payloads
-only with its pod-peers over the (slow, DCN) "pod" axis:
+Execution context: on a pod mesh these functions run INSIDE the trainer's
+per-pod shard_map, which is manual over every mesh axis, so each device
+compresses its pod's whole tree and exchanges payloads only with its
+pod-peers over the (slow, DCN) "pod" axis.  On a single-pod mesh
+compression runs in a shard_map manual over "data"/"model", so every
+device compresses exactly its local shard — no resharding:
 
     g_ef   = g + gamma * e                          (eq 7, error feedback)
     payload= codec.ef_encode(g_ef_local)             (codec from the plan)
@@ -51,7 +52,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 from repro.codecs import EDGE_AXIS, POD_AXIS, plan_wire_bytes
 from repro.core import compression as C
 from repro.core.planexec import ExecPlan, build_exec_plan, n_blocks
@@ -150,9 +150,10 @@ def _tier_info(mesh) -> Tuple[int, int]:
 
 
 def _uses_nested(mesh, inside_manual: bool) -> bool:
-    """Whether sync_tree will wrap the exchange in a nested data/model
-    shard_map (leaves become local shards there)."""
-    return mesh is not None and (compat.PARTIAL_MANUAL or not inside_manual)
+    """Whether sync_tree will wrap the exchange in a data/model shard_map
+    (leaves become local shards there).  Inside the trainer's fully-manual
+    per-pod region every axis is already manual and leaves are whole."""
+    return mesh is not None and not inside_manual
 
 
 def _local_shape(shape, spec, mesh) -> Tuple[int, ...]:
@@ -457,10 +458,10 @@ def sync_tree(tree, errors, plan: Union[SyncPlan, ExecPlan], *, mesh,
     overlaps the optimizer with the exchange: rung r's update depends
     only on rung r's collective, not on a whole-tree barrier.
 
-    ``inside_manual``: whether we are already inside a shard_map (then the
-    nested shard_map must infer the context mesh); default: pod axis
-    present.  ``use_pallas``: route the EF + compress inner loop through
-    the fused Pallas kernels; default
+    ``inside_manual``: whether we are already inside a shard_map manual
+    over every mesh axis (then the exchange runs on the whole local
+    leaves); default: pod axis present.  ``use_pallas``: route the EF +
+    compress inner loop through the fused Pallas kernels; default
     :func:`repro.kernels.ops.default_use_pallas` (kernels on accelerators,
     pure-jnp oracles on CPU, ``REPRO_FORCE_INTERPRET=1`` to force the
     kernel path under the interpreter).
@@ -534,20 +535,18 @@ def sync_tree(tree, errors, plan: Union[SyncPlan, ExecPlan], *, mesh,
         scalar_specs = tuple(P() for _ in scalars)
         out_main = (tuple(aspecs for _ in aux) if apply_fn is not None
                     else aspecs)
-        inner = compat.shard_map(
-            fn, mesh,
+        inner = jax.shard_map(
+            fn, mesh=mesh,
             in_specs=(aspecs, aspecs, pspecs, P(None), P(), P(None),
                       aux_specs, scalar_specs),
             out_specs=(out_main, aspecs),
-            manual_axes=set(_auto_axes(mesh)),
-            # surrounding per-pod shard_map (if any) provides the mesh
-            infer_mesh=inside_manual)
+            axis_names=set(_auto_axes(mesh)), check_vma=False)
         aggs, news = inner(gs, es, ep.perms, omega, omega_own,
                            omega_intra, aux, scalars)
     else:
-        # no mesh, or old-jax fully-manual region (leaves replicated
-        # over data/model there): device-local math, pod collectives
-        # still bound by the enclosing manual region
+        # no mesh, or the fully-manual per-pod region (leaves whole and
+        # replicated over data/model there): device-local math, pod
+        # collectives bound by the enclosing manual region
         aggs, news = fn(gs, es, ep.perms, omega, omega_own, omega_intra,
                         aux, scalars)
     news_tree = jax.tree_util.tree_unflatten(treedef, list(news))

@@ -1,6 +1,6 @@
 """Distributed trainer: assembles model + optimizer + ACE-Sync into per-pod
-train steps (shard_map manual over "pod"; "data"/"model" auto under XLA
-SPMD).
+train steps (one shard_map manual over every mesh axis on pod meshes;
+"data"/"model" auto under XLA SPMD on single-pod meshes).
 
 Step kinds
 ----------
@@ -42,7 +42,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro import compat
 from repro.configs.base import RunConfig
 from repro.core import acesync
 from repro.core import planexec
@@ -436,20 +435,20 @@ class Trainer:
             # plan vectors (gather perms + omega) ride replicated into the
             # per-pod manual region
             plan_in = jax.tree.map(lambda _: P(), ep)
-            # modern jax: manual over the fleet axes only, data/model auto
-            # under XLA SPMD; old jax: fully manual, data/model-replicated
-            # compute
-            manual = compat.manual_axes_for(mesh, set(self.fleet_axes))
+            # fully manual over every mesh axis: each device runs its pod's
+            # step on the pod's whole state and batch (data/model-
+            # replicated compute).  A region manual over the fleet axes
+            # only, with data/model auto, aborts XLA's SPMD partitioner on
+            # meshes where both data and model exceed 1.
 
             def wrapped(state, batch, plan_vec):
-                with use_shard_ctx(mesh, exclude=tuple(manual)):
+                with use_shard_ctx(mesh, exclude=tuple(mesh.axis_names)):
                     return body(state, batch, plan_vec)
 
-            smapped = compat.shard_map(
-                wrapped, mesh,
+            smapped = jax.shard_map(
+                wrapped, mesh=mesh,
                 in_specs=(state_in, P(fleet), plan_in),
-                out_specs=(state_in, P()),
-                manual_axes=manual)
+                out_specs=(state_in, P()), check_vma=False)
             fn = jax.jit(smapped, donate_argnums=(0,))
         # setdefault: a background warm_compile thread may race this
         # insert for the same key — both must end up sharing ONE jitted

@@ -15,7 +15,7 @@ the DCN transfer of the next chunk:
   * sign:  majority-vote partial counts: vote += w * (+-1 signs unpacked
            from the bit-packed wire), mag += w * scale
   * topk:  scatter-add the k (value, index) pairs per block into the
-           dense accumulator (one-hot lane compare per kept entry)
+           dense accumulator (one lane compare per kept entry)
 
 ``weight`` is a TRACED scalar (the omega entry of the sending pod — plan
 data, swapped per replan), so it rides as a (1, 1) operand instead of a
@@ -84,9 +84,11 @@ def unpack_signs(packed):
     """(rows, C // 8) uint8 bit-packed -> (rows, C) f32 {-1, +1} signs.
     Same bit layout as ``repro.codecs.base.unpack_bits`` (bit i of byte b
     = column 8b+i); plain jnp, so it runs inside the kernel body and in
-    the oracle ref alike."""
-    bits = ((packed[:, :, None] >>
-             jnp.arange(8, dtype=jnp.uint8)) & 1).astype(jnp.float32)
+    the oracle ref alike.  The shifts run in int32: Mosaic has no
+    uint8 -> f32 cast."""
+    p = packed.astype(jnp.int32)
+    bits = ((p[:, :, None] >> jnp.arange(8, dtype=jnp.int32)) &
+            1).astype(jnp.float32)
     return bits.reshape(packed.shape[0], packed.shape[1] * 8) * 2.0 - 1.0
 
 
@@ -163,16 +165,23 @@ def sign_vote_accum_fused(vote, mag, p, s, w, *, interpret: bool = False):
 
 def _topk_kernel(acc_ref, q_ref, i_ref, s_ref, w_ref, out_ref, *, k: int):
     w = w_ref[0, 0]
-    vals = q_ref[...].astype(jnp.float32) * s_ref[...]   # (ROWS, k) dense
-    idx = i_ref[...].astype(jnp.int32)
-    lanes = jax.lax.broadcasted_iota(jnp.int32, (ROWS, LANES), 1)
-    acc = acc_ref[...]
+    wv = w * (q_ref[...].astype(jnp.float32) * s_ref[...])   # (ROWS, k)
+    # indices < LANES are exact in f32, whose lane reductions Mosaic has
+    idx = i_ref[...].astype(jnp.int32).astype(jnp.float32)
+    col = jax.lax.broadcasted_iota(jnp.int32, (ROWS, k), 1)
+    lanes = jax.lax.broadcasted_iota(jnp.int32, (ROWS, LANES), 1
+                                     ).astype(jnp.float32)
 
     def body(j, acc):
-        hot = (lanes == idx[:, j][:, None]).astype(jnp.float32)
-        return acc + hot * (w * vals[:, j][:, None])
+        # entry j of every row, picked by a masked lane sum (Mosaic has
+        # no per-j dynamic lane slice); the sum adds exact zeros only
+        pick = col == j
+        lane = jnp.sum(jnp.where(pick, idx, 0.0), axis=1, keepdims=True)
+        val = jnp.sum(jnp.where(pick, wv, 0.0), axis=1, keepdims=True)
+        # only the hit lane changes, as in the oracle's scatter-add
+        return jnp.where(lanes == lane, acc + val, acc)
 
-    out_ref[...] = jax.lax.fori_loop(0, k, body, acc)
+    out_ref[...] = jax.lax.fori_loop(0, k, body, acc_ref[...])
 
 
 def _int8_fp_kernel(acc_ref, q_ref, s_ref, w_ref, out_ref, *, bits: int):

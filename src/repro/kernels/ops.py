@@ -1,23 +1,24 @@
 """Public jit'd wrappers for the compression kernels.
 
-On TPU these dispatch to the compiled Pallas kernels; on CPU (this
-container, and any unit-test environment) they run the same kernel bodies
-under ``interpret=True``.  ``use_pallas=False`` falls back to the pure-jnp
-oracle — the path the CPU dry-run lowers, keeping kernel code out of the
-roofline HLO while the math stays identical.
+On TPU these dispatch to the compiled Pallas kernels; on CPU (unit-test
+environments) they run the same kernel bodies under ``interpret=True``.
+``use_pallas=False`` falls back to the pure-jnp oracle — the path the CPU
+dry-run lowers, keeping kernel code out of the roofline HLO while the
+math stays identical.
 
 Backend dispatch is decided ONCE per process (the sync hot loop calls
-these per bucket per step; re-querying ``jax.default_backend()`` on every
-call was measurable on the host-side trace).  Two cached predicates:
+these per bucket per step).  Two cached predicates:
 
   * :func:`interpret_mode` — should ``pallas_call`` interpret?  True on
-    CPU, False on accelerators; ``REPRO_FORCE_INTERPRET=1`` forces True
-    (CI runs the kernel bodies even on CPU runners), ``=0`` forces False.
+    CPU, False on accelerators.
   * :func:`default_use_pallas` — should the sync path route through the
-    kernels at all?  True on accelerators (the fused path is the one
-    ``grad_sync`` / ``delta_sync`` exercise there); False on CPU where the
-    interpreted kernels would only slow the oracle math down — unless
-    ``REPRO_FORCE_INTERPRET=1`` opts CI into the kernel path.
+    kernels at all?  True on accelerators; False on CPU, where the
+    interpreted kernels would only slow the oracle math down, unless
+    ``REPRO_FORCE_INTERPRET=1`` opts CPU CI into the interpreted kernel
+    path.
+
+``REPRO_FORCE_INTERPRET`` is a CPU-only switch: set on any other backend
+it raises, so nothing can put the kernels into interpret mode on a chip.
 """
 from __future__ import annotations
 
@@ -27,7 +28,7 @@ import os
 import jax
 import jax.numpy as jnp
 
-from repro.kernels import autotune, ref
+from repro.kernels import ref
 from repro.kernels.topk_compress import (ef_topk_gather, ef_topk_select,
                                          LANES, ROWS)
 from repro.kernels.decode import (dequant_accum_int4_fp_fused,
@@ -45,36 +46,31 @@ from repro.kernels.sign import ef_sign_fused, ef_sign_gather
 FORCE_INTERPRET_ENV = "REPRO_FORCE_INTERPRET"
 
 
-def _env_force():
-    v = os.environ.get(FORCE_INTERPRET_ENV)
-    if v is None:
-        return None
-    return v.strip().lower() not in ("", "0", "false", "no")
+def _force_interpret() -> bool:
+    """``REPRO_FORCE_INTERPRET`` as a bool; an error off the CPU."""
+    v = os.environ.get(FORCE_INTERPRET_ENV, "").strip().lower()
+    forced = v not in ("", "0", "false", "no")
+    if forced and jax.default_backend() != "cpu":
+        raise RuntimeError(
+            f"{FORCE_INTERPRET_ENV}={v} is a CPU-only switch; the "
+            f"{jax.default_backend()} backend runs the compiled kernels")
+    return forced
 
 
 @functools.lru_cache(maxsize=None)
 def interpret_mode() -> bool:
-    """Whether pallas_call should run interpreted (cached per process)."""
-    forced = _env_force()
-    if forced is not None:
-        return forced
+    """Whether pallas_call should run interpreted (cached per process):
+    exactly when the backend is the CPU."""
+    _force_interpret()
     return jax.default_backend() == "cpu"
 
 
 @functools.lru_cache(maxsize=None)
 def default_use_pallas() -> bool:
     """Default ``use_pallas`` for the sync hot path (cached per process):
-    compiled kernels on accelerators, oracle math on CPU.
-    ``REPRO_FORCE_INTERPRET=1`` additionally opts CPU/CI into the
-    (interpreted) kernel path; ``=0`` only disables interpretation and
-    never turns the compiled kernels off on accelerators."""
-    if _env_force():
-        return True
-    return jax.default_backend() != "cpu"
-
-
-def _on_cpu() -> bool:  # kept for external callers; now cached
-    return interpret_mode()
+    compiled kernels on accelerators, oracle math on CPU unless
+    ``REPRO_FORCE_INTERPRET=1``."""
+    return _force_interpret() or jax.default_backend() != "cpu"
 
 
 def pad_rows(flat: jax.Array):
@@ -250,109 +246,50 @@ def ef_sign(g_flat, e_flat, *, gamma: float, use_pallas: bool = True):
 # These read a rung's rows straight out of the packed (NB+1, LANES)
 # grad / error buffers through the plan's gather perm — the gathered
 # bucket never materialises between the backward pass and the encode.
-# The rows-per-grid-step tile height comes from the autotune cache
-# (repro/kernels/autotune.py), measured once per (codec, size-class,
-# backend); interpret mode always takes the deterministic default and
-# never touches the cache file.
-
-
-def _pad_perm(perm, rows: int, zero_idx: int):
-    """Pad the gather perm to a ``rows`` multiple with the zero-row
-    index (padded tail rows encode zeros and are sliced off)."""
-    S = perm.shape[0]
-    pad = (-S) % rows
-    if pad:
-        perm = jnp.concatenate(
-            [perm, jnp.full((pad,), zero_idx, perm.dtype)])
-    return perm, S
-
-
-def _gather_bench(kern, nbp1: int, S: int, **kw):
-    """Autotune measurement closure: wall-time ``kern`` at a candidate
-    tile height on representative synthetic shapes.  Runs EAGERLY on
-    the live backend (only ever invoked outside interpret mode — on
-    accelerators, where the compiled kernels are real)."""
-    import time
-
-    def bench(rows: int) -> float:
-        fb = jax.random.normal(jax.random.PRNGKey(0), (nbp1, LANES),
-                               jnp.float32)
-        eb = fb * 0.5
-        sp = ((S + rows - 1) // rows) * rows
-        perm = (jnp.arange(sp, dtype=jnp.int32) % max(1, nbp1 - 1))
-        out = kern(fb, eb, perm, rows=rows, **kw)   # compile + warm
-        jax.block_until_ready(out)
-        t0 = time.perf_counter()
-        for _ in range(3):
-            out = kern(fb, eb, perm, rows=rows, **kw)
-        jax.block_until_ready(out)
-        return (time.perf_counter() - t0) / 3
-
-    return bench
-
-
-def _gather_rows(codec: str, kern, fb, perm, **kw) -> int:
-    bench = None
-    if not interpret_mode():
-        bench = _gather_bench(kern, int(fb.shape[0]), int(perm.shape[0]),
-                              **kw)
-    return autotune.block_rows(codec, int(perm.shape[0]), bench=bench)
 
 
 def gather_ef_int8(fb, eb, perm, *, gamma: float, use_pallas: bool = True):
     """Fused gather + EF + int8 encode of one rung's rows.
     Returns (q (S, LANES) int8, scales (S, 1) f32, residual (S*LANES,))."""
-    if not use_pallas:
+    if use_pallas:
+        q, s, r = quantize_int8_gather(fb, eb, perm, gamma=gamma,
+                                       interpret=interpret_mode())
+    else:
         q, s, r = ref.quantize_int8_gather_ref(fb, eb, perm, gamma=gamma)
-        return q, s, r.reshape(-1)
-    rows = _gather_rows("int8", quantize_int8_gather, fb, perm,
-                        gamma=gamma, interpret=False)
-    p2, S = _pad_perm(perm, rows, fb.shape[0] - 1)
-    q, s, r = quantize_int8_gather(fb, eb, p2, gamma=gamma, rows=rows,
-                                   interpret=interpret_mode())
-    return q[:S], s[:S], r[:S].reshape(-1)
+    return q, s, r.reshape(-1)
 
 
 def gather_ef_int4(fb, eb, perm, *, gamma: float, use_pallas: bool = True):
     """Fused gather + EF + packed-int4 encode of one rung's rows.
     Returns (packed (S, LANES//2) uint8, scales (S, 1) f32,
     residual (S*LANES,))."""
-    if not use_pallas:
+    if use_pallas:
+        p, s, r = ef_int4_gather(fb, eb, perm, gamma=gamma,
+                                 interpret=interpret_mode())
+    else:
         p, s, r = ref.ef_int4_gather_ref(fb, eb, perm, gamma=gamma)
-        return p, s, r.reshape(-1)
-    rows = _gather_rows("int4", ef_int4_gather, fb, perm,
-                        gamma=gamma, interpret=False)
-    p2, S = _pad_perm(perm, rows, fb.shape[0] - 1)
-    p, s, r = ef_int4_gather(fb, eb, p2, gamma=gamma, rows=rows,
-                             interpret=interpret_mode())
-    return p[:S], s[:S], r[:S].reshape(-1)
+    return p, s, r.reshape(-1)
 
 
 def gather_ef_sign(fb, eb, perm, *, gamma: float, use_pallas: bool = True):
     """Fused gather + EF + 1-bit sign encode of one rung's rows.
     Returns (sign (S, LANES) int8, scales (S, 1) f32,
     residual (S*LANES,))."""
-    if not use_pallas:
+    if use_pallas:
+        sg, s, r = ef_sign_gather(fb, eb, perm, gamma=gamma,
+                                  interpret=interpret_mode())
+    else:
         sg, s, r = ref.ef_sign_gather_ref(fb, eb, perm, gamma=gamma)
-        return sg, s, r.reshape(-1)
-    rows = _gather_rows("sign", ef_sign_gather, fb, perm,
-                        gamma=gamma, interpret=False)
-    p2, S = _pad_perm(perm, rows, fb.shape[0] - 1)
-    sg, s, r = ef_sign_gather(fb, eb, p2, gamma=gamma, rows=rows,
-                              interpret=interpret_mode())
-    return sg[:S], s[:S], r[:S].reshape(-1)
+    return sg, s, r.reshape(-1)
 
 
 def gather_ef_topk(fb, eb, perm, *, gamma: float, k: int,
                    use_pallas: bool = True):
     """Fused gather + EF + block top-k selection of one rung's rows.
     Returns (selected_dense (S, LANES) f32, residual (S*LANES,))."""
-    if not use_pallas:
+    if use_pallas:
+        sel, res = ef_topk_gather(fb, eb, perm, gamma=gamma, k=k,
+                                  interpret=interpret_mode())
+    else:
         sel, res = ref.ef_topk_gather_ref(fb, eb, perm, gamma=gamma, k=k)
-        return sel, res.reshape(-1)
-    rows = _gather_rows("topk", ef_topk_gather, fb, perm,
-                        gamma=gamma, k=k, interpret=False)
-    p2, S = _pad_perm(perm, rows, fb.shape[0] - 1)
-    sel, res = ef_topk_gather(fb, eb, p2, gamma=gamma, k=k, rows=rows,
-                              interpret=interpret_mode())
-    return sel[:S], res[:S].reshape(-1)
+    return sel, res.reshape(-1)

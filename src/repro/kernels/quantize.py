@@ -6,9 +6,10 @@ versus three separate HBM passes in the naive formulation.  Two rungs live
 here:
 
   * int8: absmax/127 scale, one byte per value;
-  * int4: absmax/7 scale, two values packed per byte (low nibble first,
-    offset-binary q+8), fused with the error-feedback accumulate
-    ``ef = g + gamma*e`` so the INT4 sync rung is one HBM pass end-to-end.
+  * int4: absmax/7 scale, two values packed per byte (first half of the
+    block in the low nibbles, offset-binary q+8), fused with the
+    error-feedback accumulate ``ef = g + gamma*e`` so the INT4 sync rung
+    is one HBM pass end-to-end.
 """
 from __future__ import annotations
 
@@ -64,8 +65,8 @@ def quantize_int8_fused(x, *, interpret: bool = False):
     return q, s, r
 
 
-@functools.partial(jax.jit, static_argnames=("gamma", "rows", "interpret"))
-def quantize_int8_gather(fb, eb, perm, *, gamma: float, rows: int = 1,
+@functools.partial(jax.jit, static_argnames=("gamma", "interpret"))
+def quantize_int8_gather(fb, eb, perm, *, gamma: float,
                          interpret: bool = False):
     """Producer-fused gather + EF + int8 quantise: the rung's rows are
     read straight out of the (NB+1, LANES) grad / error buffers through
@@ -79,8 +80,7 @@ def quantize_int8_gather(fb, eb, perm, *, gamma: float, rows: int = 1,
         return q, scale, ef - q * scale
 
     out_defs = [(LANES, jnp.int8), (1, jnp.float32), (LANES, jnp.float32)]
-    return gather_ef_call(body, fb, eb, perm, out_defs, rows=rows,
-                          interpret=interpret)
+    return gather_ef_call(body, fb, eb, perm, out_defs, interpret=interpret)
 
 
 # ---------------------------------------------------------------------------
@@ -97,19 +97,22 @@ def _int4_body(x):
 
 
 def pack_nibbles(q):
-    """(rows, C) f32 in [-7, 7] -> (rows, C // 2) uint8 (offset binary
-    q+8; even column in the low nibble)."""
-    u = (q + 8.0).astype(jnp.uint8)
-    u3 = u.reshape(q.shape[0], q.shape[1] // 2, 2)
-    return u3[..., 0] | (u3[..., 1] << 4)
+    """(rows, C) f32 in [-7, 7] -> (rows, C // 2) uint8, offset binary
+    q+8: column j in the low nibble of byte j, column j + C/2 in its high
+    nibble.  Splitting by halves keeps every lane slice static and
+    128-aligned (Mosaic cannot de-interleave even/odd columns), and the
+    integer math runs in int32 (Mosaic has no f32 <-> uint8 casts)."""
+    u = (q + 8.0).astype(jnp.int32)
+    h = q.shape[1] // 2
+    return (u[:, :h] | (u[:, h:] << 4)).astype(jnp.uint8)
 
 
 def unpack_nibbles(packed):
     """Inverse of :func:`pack_nibbles` -> (rows, 2 * C') f32."""
-    lo = (packed & 0xF).astype(jnp.float32) - 8.0
-    hi = (packed >> 4).astype(jnp.float32) - 8.0
-    return jnp.stack([lo, hi], axis=-1).reshape(packed.shape[0],
-                                                packed.shape[1] * 2)
+    p = packed.astype(jnp.int32)
+    lo = (p & 0xF).astype(jnp.float32) - 8.0
+    hi = (p >> 4).astype(jnp.float32) - 8.0
+    return jnp.concatenate([lo, hi], axis=1)
 
 
 def _int4_kernel(g_ref, e_ref, p_ref, s_ref, r_ref, *, gamma: float):
@@ -147,8 +150,8 @@ def ef_int4_fused(g, e, *, gamma: float, interpret: bool = False):
     return p, s, r
 
 
-@functools.partial(jax.jit, static_argnames=("gamma", "rows", "interpret"))
-def ef_int4_gather(fb, eb, perm, *, gamma: float, rows: int = 1,
+@functools.partial(jax.jit, static_argnames=("gamma", "interpret"))
+def ef_int4_gather(fb, eb, perm, *, gamma: float,
                    interpret: bool = False):
     """Producer-fused gather + EF + packed-int4 quantise through ``perm``.
     Returns (packed (S, LANES//2) uint8, scales (S, 1) f32, residual
@@ -161,8 +164,7 @@ def ef_int4_gather(fb, eb, perm, *, gamma: float, rows: int = 1,
 
     out_defs = [(LANES // 2, jnp.uint8), (1, jnp.float32),
                 (LANES, jnp.float32)]
-    return gather_ef_call(body, fb, eb, perm, out_defs, rows=rows,
-                          interpret=interpret)
+    return gather_ef_call(body, fb, eb, perm, out_defs, interpret=interpret)
 
 
 def _dequant_kernel(q_ref, s_ref, out_ref):

@@ -26,9 +26,23 @@ from jax.experimental import pallas as pl
 from repro.kernels.topk_compress import LANES, ROWS, gather_ef_call
 
 
+def lane_mean(x):
+    """Mean over the last axis, (rows, C) -> (rows, 1), summed by halving
+    with static slices: the same adds in the same order on every backend.
+    A reduce leaves the order to the compiler, and Mosaic and XLA pick
+    different ones, so their means differ in the last bit.  Widths that
+    are not a power of two finish with a plain sum."""
+    w = x.shape[-1]
+    n = w
+    while w > 1 and w % 2 == 0:
+        w //= 2
+        x = x[:, :w] + x[:, w:]
+    return jnp.sum(x, axis=-1, keepdims=True) / n
+
+
 def _sign_body(x):
     """Shared math (kernel + oracle). x: (rows, LANES) f32."""
-    scale = jnp.mean(jnp.abs(x), axis=-1, keepdims=True)
+    scale = lane_mean(jnp.abs(x))
     sign = jnp.where(x >= 0, 1.0, -1.0)
     return sign, scale
 
@@ -68,8 +82,8 @@ def ef_sign_fused(g, e, *, gamma: float, interpret: bool = False):
     return sign, s, r
 
 
-@functools.partial(jax.jit, static_argnames=("gamma", "rows", "interpret"))
-def ef_sign_gather(fb, eb, perm, *, gamma: float, rows: int = 1,
+@functools.partial(jax.jit, static_argnames=("gamma", "interpret"))
+def ef_sign_gather(fb, eb, perm, *, gamma: float,
                    interpret: bool = False):
     """Producer-fused gather + EF + 1-bit sign compression through
     ``perm``.  Returns (sign (S, LANES) int8, scales (S, 1) f32,
@@ -82,5 +96,4 @@ def ef_sign_gather(fb, eb, perm, *, gamma: float, rows: int = 1,
         return sign, scale, ef - sign * scale
 
     out_defs = [(LANES, jnp.int8), (1, jnp.float32), (LANES, jnp.float32)]
-    return gather_ef_call(body, fb, eb, perm, out_defs, rows=rows,
-                          interpret=interpret)
+    return gather_ef_call(body, fb, eb, perm, out_defs, interpret=interpret)

@@ -20,7 +20,9 @@ format carries a count, so correctness does not depend on exact k (DGC
 makes the same trade).
 
 Block geometry: tiles are (ROWS, LANES) = (8, 1024) f32 = 32 KiB in VMEM —
-8 sublanes x 128-lane multiples, MXU/VPU aligned.
+8 sublanes x 128-lane multiples, MXU/VPU aligned.  The producer-fused
+gather kernels fetch one (1, LANES) row per grid step through a 3-D view
+(see :func:`gather_ef_call`).
 """
 from __future__ import annotations
 
@@ -41,67 +43,69 @@ BISECT_ITERS = 16
 # ---------------------------------------------------------------------------
 
 
-def gather_ef_call(body, fb, eb, perm, out_defs, *, rows: int,
+#: most gather rows one pallas_call takes: its perm rides in scalar
+#: memory (1 MiB of SMEM on a v5e), which holds ~256K int32 indices
+MAX_GATHER_ROWS = 131072
+
+
+def gather_ef_call(body, fb, eb, perm, out_defs, *,
                    interpret: bool = False):
     """Run a per-row encode ``body`` directly on gathered bucket rows.
 
     ``fb`` / ``eb``: the packed (NB+1, LANES) grad / error-feedback
-    buffers (zero row last); ``perm``: (S,) int32 block indices, S a
-    multiple of ``rows``.  ``body(g, e) -> tuple`` maps (r, LANES) f32
-    row tiles to the per-row encode outputs; ``out_defs`` lists each
-    output's ``(width, dtype)`` (outputs are (S, width)).
+    buffers (zero row last); ``perm``: (S,) int32 block indices.
+    ``body(g, e) -> tuple`` maps (1, LANES) f32 row tiles to the per-row
+    encode outputs; ``out_defs`` lists each output's ``(width, dtype)``
+    (outputs are (S, width)).
 
-    The gather never materialises in HBM.  Two lowerings, picked by the
-    autotuner (``repro.kernels.autotune.block_rows``):
+    The gather never materialises in HBM: the perm rides in
+    scalar-prefetch memory and the input index map reads block
+    ``perm[i]`` per grid step, so Pallas's pipeline does the gather while
+    fetching the row.  Every operand is viewed 3-D, (rows, 1, width),
+    with (None, 1, width) blocks: a (1, width) block of a 2-D array
+    breaks the TPU's (8, 128) tiling rule, while as the last two dims of
+    a 3-D view it equals the array's own dims.  The views are free
+    reshapes.
 
-      * ``rows == 1``: the perm rides in scalar-prefetch memory and the
-        input index map reads block ``perm[i]`` per grid step — Pallas's
-        pipeline does the gather while fetching the tile;
-      * ``rows > 1``: the whole buffer is the block and the kernel
-        dynamic-slices ``rows`` indexed rows per step — fewer grid
-        steps, more work (and VMEM) per step.
-
-    Both produce bit-identical outputs (same per-row math, same f32
-    order); only wall time differs.
+    A perm longer than :data:`MAX_GATHER_ROWS` (more than scalar memory
+    holds) runs as several calls over consecutive perm chunks; each call
+    writes its rows in place into the shared outputs (aliased through),
+    so no chunk is copied again.
     """
     S = perm.shape[0]
-    assert S % rows == 0, (S, rows)
     nbp1, lanes = fb.shape
+    perm = perm.astype(jnp.int32)
+    fb3, eb3 = fb.reshape(nbp1, 1, lanes), eb.reshape(nbp1, 1, lanes)
+    out_shape = [jax.ShapeDtypeStruct((S, 1, w), dt) for w, dt in out_defs]
+    row_spec = pl.BlockSpec((None, 1, lanes), lambda i, p: (p[i], 0, 0))
 
-    def kernel_r1(p_ref, g_ref, e_ref, *out_refs):
-        outs = body(g_ref[...], e_ref[...])
-        for ref, o in zip(out_refs, outs):
-            ref[...] = o.astype(ref.dtype)
+    def call(p_chunk, start, outs):
+        def kernel(p_ref, g_ref, e_ref, *refs):
+            for ref, o in zip(refs[len(outs):],
+                              body(g_ref[...], e_ref[...])):
+                ref[...] = o.astype(ref.dtype)
 
-    def kernel_rn(p_ref, g_ref, e_ref, *out_refs):
-        i = pl.program_id(0)
-        for r in range(rows):
-            idx = p_ref[i * rows + r]
-            g = pl.load(g_ref, (pl.dslice(idx, 1), slice(None)))
-            e = pl.load(e_ref, (pl.dslice(idx, 1), slice(None)))
-            outs = body(g, e)
-            for ref, o in zip(out_refs, outs):
-                pl.store(ref, (pl.dslice(r, 1), slice(None)),
-                         o.astype(ref.dtype))
-
-    if rows == 1:
-        in_specs = [pl.BlockSpec((1, lanes), lambda i, p: (p[i], 0))] * 2
-        out_specs = [pl.BlockSpec((1, w), lambda i, p: (i, 0))
+        out_specs = [pl.BlockSpec((None, 1, w),
+                                  lambda i, p: (i + start, 0, 0))
                      for w, _ in out_defs]
-        grid, kernel = (S,), kernel_r1
+        grid_spec = pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(p_chunk.shape[0],),
+            in_specs=[row_spec, row_spec]
+            + [pl.BlockSpec(memory_space=pl.ANY)] * len(outs),
+            out_specs=out_specs)
+        return pl.pallas_call(
+            kernel, grid_spec=grid_spec, out_shape=out_shape,
+            input_output_aliases={3 + j: j for j in range(len(outs))},
+            interpret=interpret,
+        )(p_chunk, fb3, eb3, *outs)
+
+    if S <= MAX_GATHER_ROWS:
+        outs = call(perm, 0, [])
     else:
-        in_specs = [pl.BlockSpec((nbp1, lanes), lambda i, p: (0, 0))] * 2
-        out_specs = [pl.BlockSpec((rows, w), lambda i, p: (i, 0))
-                     for w, _ in out_defs]
-        grid, kernel = (S // rows,), kernel_rn
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1, grid=grid, in_specs=in_specs,
-        out_specs=out_specs)
-    return pl.pallas_call(
-        kernel, grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct((S, w), dt) for w, dt in out_defs],
-        interpret=interpret,
-    )(perm.astype(jnp.int32), fb, eb)
+        outs = [jnp.zeros(o.shape, o.dtype) for o in out_shape]
+        for start in range(0, S, MAX_GATHER_ROWS):
+            outs = call(perm[start:start + MAX_GATHER_ROWS], start, outs)
+    return tuple(o.reshape(S, w) for o, (w, _) in zip(outs, out_defs))
 
 
 def _select_body(ef, k):
@@ -154,9 +158,8 @@ def ef_topk_select(g, e, *, gamma: float, k: int, interpret: bool = False):
     return out[0], out[1]
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("gamma", "k", "rows", "interpret"))
-def ef_topk_gather(fb, eb, perm, *, gamma: float, k: int, rows: int = 1,
+@functools.partial(jax.jit, static_argnames=("gamma", "k", "interpret"))
+def ef_topk_gather(fb, eb, perm, *, gamma: float, k: int,
                    interpret: bool = False):
     """Producer-fused gather + EF + top-k selection: reads the rung's
     rows straight out of the (NB+1, LANES) buffers through ``perm``.
@@ -170,5 +173,4 @@ def ef_topk_gather(fb, eb, perm, *, gamma: float, k: int, rows: int = 1,
         return sel, ef - sel
 
     out_defs = [(LANES, jnp.float32), (LANES, jnp.float32)]
-    return gather_ef_call(body, fb, eb, perm, out_defs, rows=rows,
-                          interpret=interpret)
+    return gather_ef_call(body, fb, eb, perm, out_defs, interpret=interpret)

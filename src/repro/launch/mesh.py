@@ -11,19 +11,14 @@ from __future__ import annotations
 import jax
 
 
-def _axis_type_kwargs(n_axes: int) -> dict:
-    """jax >= 0.5 wants explicit axis_types; older jax (no
-    jax.sharding.AxisType) defaults every axis to Auto already."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n_axes}
+def _auto(n_axes: int) -> tuple:
+    return (jax.sharding.AxisType.Auto,) * n_axes
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_axis_type_kwargs(len(axes)))
+    return jax.make_mesh(shape, axes, axis_types=_auto(len(axes)))
 
 
 def make_mesh(shape, axes, devices=None):
@@ -34,14 +29,14 @@ def make_mesh(shape, axes, devices=None):
     pod drops out (jax.make_mesh always spans the full inventory)."""
     shape, axes = tuple(shape), tuple(axes)
     if devices is None:
-        return jax.make_mesh(shape, axes, **_axis_type_kwargs(len(axes)))
+        return jax.make_mesh(shape, axes, axis_types=_auto(len(axes)))
     import numpy as np
     need = int(np.prod(shape))
     if len(devices) < need:
         raise ValueError(f"mesh {shape} needs {need} devices, "
                          f"got {len(devices)}")
     grid = np.asarray(devices[:need], dtype=object).reshape(shape)
-    return jax.sharding.Mesh(grid, axes, **_axis_type_kwargs(len(axes)))
+    return jax.sharding.Mesh(grid, axes, axis_types=_auto(len(axes)))
 
 
 # Hardware constants for the roofline analysis (TPU v5e)

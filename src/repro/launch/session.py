@@ -14,6 +14,7 @@ or a :class:`~repro.strategies.SyncStrategy` instance works.
 """
 from __future__ import annotations
 
+import dataclasses
 from typing import Optional, Union
 
 import jax
@@ -21,6 +22,7 @@ import jax
 from repro.configs import ARCHS, SMOKE_ARCHS
 from repro.configs.base import RunConfig, ShapeConfig
 from repro.data.pipeline import TokenPipeline
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.train import TrainLoop
 from repro.models.registry import build_model
 from repro.strategies import SyncStrategy
@@ -34,6 +36,7 @@ class TrainSession:
                  n_edge_devices: int = 8, seed: int = 0,
                  fault_schedule=None, elastic: bool = True,
                  blocking_replans: bool = False):
+        enable_compile_cache()
         self.model = model
         self.run_config = run
         self.mesh = mesh
@@ -51,12 +54,17 @@ class TrainSession:
                     strategy: Union[str, SyncStrategy] = "acesync",
                     mesh=None, *, smoke: bool = True, seq_len: int = 256,
                     batch: int = 8, steps: int = 100,
+                    n_layers: Optional[int] = None,
                     n_edge_devices: int = 8, seed: int = 0,
                     fault_schedule=None, elastic: bool = True,
                     blocking_replans: bool = False,
                     **run_kw) -> "TrainSession":
-        """Build a session from an architecture name + strategy spec."""
+        """Build a session from an architecture name + strategy spec.
+        ``n_layers`` cuts the model's depth and keeps its widths: how a
+        full-width configuration is fitted to one chip's memory."""
         cfg = (SMOKE_ARCHS if smoke else ARCHS)[arch]
+        if n_layers is not None:
+            cfg = dataclasses.replace(cfg, n_layers=n_layers)
         shape = ShapeConfig("session", seq_len, batch, "train")
         run_kw.setdefault("warmup_steps", max(2, steps // 10))
         run = RunConfig(model=cfg, shape=shape, total_steps=steps, **run_kw)

@@ -618,7 +618,10 @@ class TrainLoop:
 
 
 def main():
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.launch.session import TrainSession
+
+    enable_compile_cache()
 
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="paper-350m")
@@ -629,6 +632,8 @@ def main():
     ap.add_argument("--steps", type=int, default=100)
     ap.add_argument("--seq-len", type=int, default=256)
     ap.add_argument("--batch", type=int, default=8)
+    ap.add_argument("--layers", type=int, default=None,
+                    help="cut the model's depth, keeping its widths")
     ap.add_argument("--ckpt-dir", default="/tmp/repro_ckpt")
     ap.add_argument("--ckpt-every", type=int, default=None,
                     help="checkpoint cadence in steps (default: RunConfig)")
@@ -640,7 +645,7 @@ def main():
     sess = TrainSession.from_config(
         args.arch, strategy=args.strategy, smoke=args.smoke,
         seq_len=args.seq_len, batch=args.batch, steps=args.steps,
-        warmup_steps=10, ckpt_dir=args.ckpt_dir, **run_kw)
+        n_layers=args.layers, warmup_steps=10, ckpt_dir=args.ckpt_dir, **run_kw)
     sess.run(args.steps)
     sess.finish()
     losses = sess.losses

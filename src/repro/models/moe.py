@@ -16,8 +16,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
-
 
 def moe_init(rng, cfg, n_layers: int):
     D, Fe, E = cfg.d_model, cfg.d_ff, cfg.n_experts
@@ -133,8 +131,9 @@ def moe_apply(p, x, cfg):
     excl = current_exclude()
     names = set(mesh.axis_names) - set(excl)
     if not names:
-        # fully-manual enclosing region (old-jax compat): tokens/weights
-        # are device-local replicas — run the single-device math
+        # fully-manual enclosing region (the per-pod train step):
+        # tokens/weights are device-local replicas — run the single-device
+        # math
         return local(x, p["router"], p["w_gate"], p["w_up"], p["w_down"])
     ep_axis = "model" if ("model" in names and E % mesh.shape["model"] == 0) \
         else None
@@ -157,13 +156,11 @@ def moe_apply(p, x, cfg):
     out_spec = x_spec
 
     fn = functools.partial(local, ep_axis=ep_axis, fsdp_axis=fsdp)
-    smapped = compat.shard_map(
-        fn, mesh,
+    smapped = jax.shard_map(
+        fn, mesh=mesh,
         in_specs=(x_spec, w_specs["router"], w_specs["w_gate"],
                   w_specs["w_up"], w_specs["w_down"]),
-        out_specs=out_spec, manual_axes=names,
-        # enclosing manual region (excl) provides the context mesh
-        infer_mesh=bool(excl))
+        out_specs=out_spec, axis_names=names, check_vma=False)
     return smapped(x, p["router"], p["w_gate"], p["w_up"], p["w_down"])
 
 

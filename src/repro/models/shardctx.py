@@ -16,8 +16,6 @@ from typing import Optional, Union
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro import compat
-
 _state = threading.local()
 
 # canonical axes
@@ -91,24 +89,14 @@ def fit_spec(spec: P, shape, mesh: Mesh, exclude: tuple = ()) -> P:
 
 
 def constrain(x, spec: P):
-    """with_sharding_constraint against the ambient mesh (no-op without one).
-
-    Inside a shard_map manual region (exclude set) a concrete
-    NamedSharding's mesh would clash with the context AbstractMesh whose
-    manual axes differ — a bare PartitionSpec resolves against the context
-    mesh instead."""
+    """with_sharding_constraint against the ambient mesh (no-op without
+    one, and inside the fully-manual per-pod region)."""
     mesh = current_mesh()
     if mesh is None:
         return x
     if set(current_exclude()) >= set(mesh.axis_names):
         return x  # fully-manual region: nothing left to constrain
     fitted = fit_spec(spec, x.shape, mesh, current_exclude())
-    if current_exclude():
-        if compat.PARTIAL_MANUAL:
-            return jax.lax.with_sharding_constraint(x, fitted)
-        # old jax: bare specs only resolve under a physical-mesh context
-        with mesh:
-            return jax.lax.with_sharding_constraint(x, fitted)
     return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, fitted))
 
 

@@ -263,6 +263,34 @@ class TestPallasPass:
         check_record(rec, rep)
         assert any("does not divide" in v.message for v in rep.errors())
 
+    def test_untiled_gather_row_block_trips(self):
+        """The (1, 1024) one-row gather block on a 2-D (NB+1, 1024)
+        buffer: it divides the operand, yet Mosaic refuses it."""
+        from jax.experimental import pallas as pl
+        rec = PallasCallRecord(
+            kernel_name="row_gather", grid=(8,),
+            in_specs=[pl.BlockSpec((1, 1024), lambda i, p: (p[i], 0))],
+            out_specs=[pl.BlockSpec((1, 1024), lambda i, p: (i, 0))],
+            in_shapes=[(12, 1024)], out_shapes=[(8, 1024)],
+            num_scalar_prefetch=1)
+        rep = AuditReport()
+        check_record(rec, rep)
+        msgs = [v.message for v in rep.errors()]
+        assert len(msgs) == 2 and all("tiling rule" in m for m in msgs)
+        # the 3-D view the kernels ship: the row block is the whole of
+        # the last two dims
+        rec3 = PallasCallRecord(
+            kernel_name="row_gather_3d", grid=(8,),
+            in_specs=[pl.BlockSpec((None, 1, 1024),
+                                   lambda i, p: (p[i], 0, 0))],
+            out_specs=[pl.BlockSpec((None, 1, 1024),
+                                    lambda i, p: (i, 0, 0))],
+            in_shapes=[(12, 1, 1024)], out_shapes=[(8, 1, 1024)],
+            num_scalar_prefetch=1)
+        rep3 = AuditReport()
+        check_record(rec3, rep3)
+        assert rep3.ok, rep3.summary()
+
     def test_out_of_bounds_index_map_trips(self):
         from jax.experimental import pallas as pl
         rec = PallasCallRecord(

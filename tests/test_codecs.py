@@ -618,30 +618,52 @@ class TestWidenedLadder:
 
 
 # ---------------------------------------------------------------------------
-# backend dispatch caching (the hoisted _on_cpu satellite)
+# backend dispatch caching
 # ---------------------------------------------------------------------------
 
 
 class TestDispatchCaching:
-    def test_cached_and_env_override(self, monkeypatch):
-        from repro.kernels import ops
+    @staticmethod
+    def _clear(ops):
         ops.interpret_mode.cache_clear()
         ops.default_use_pallas.cache_clear()
+
+    def test_cached_and_env_override(self, monkeypatch):
+        from repro.kernels import ops
         try:
             monkeypatch.setenv(ops.FORCE_INTERPRET_ENV, "1")
-            ops.interpret_mode.cache_clear()
-            ops.default_use_pallas.cache_clear()
+            self._clear(ops)
             assert ops.interpret_mode() is True
             assert ops.default_use_pallas() is True
+            # on the CPU the kernels always interpret; "0" only keeps the
+            # sync path on the oracle math
             monkeypatch.setenv(ops.FORCE_INTERPRET_ENV, "0")
-            ops.interpret_mode.cache_clear()
-            ops.default_use_pallas.cache_clear()
-            assert ops.interpret_mode() is False
+            self._clear(ops)
+            assert ops.interpret_mode() is True
             assert ops.default_use_pallas() is False
             # cached: flipping the env without a cache clear is invisible
             monkeypatch.setenv(ops.FORCE_INTERPRET_ENV, "1")
-            assert ops.interpret_mode() is False
+            assert ops.default_use_pallas() is False
         finally:
             monkeypatch.delenv(ops.FORCE_INTERPRET_ENV, raising=False)
-            ops.interpret_mode.cache_clear()
-            ops.default_use_pallas.cache_clear()
+            self._clear(ops)
+
+    def test_accelerator_never_interprets(self, monkeypatch):
+        """Off the CPU the kernels compile, and the force switch is an
+        error instead of a silent fallback to the interpreter."""
+        from repro.kernels import ops
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        try:
+            monkeypatch.delenv(ops.FORCE_INTERPRET_ENV, raising=False)
+            self._clear(ops)
+            assert ops.interpret_mode() is False
+            assert ops.default_use_pallas() is True
+            monkeypatch.setenv(ops.FORCE_INTERPRET_ENV, "1")
+            self._clear(ops)
+            with pytest.raises(RuntimeError, match="CPU-only"):
+                ops.interpret_mode()
+            with pytest.raises(RuntimeError, match="CPU-only"):
+                ops.default_use_pallas()
+        finally:
+            monkeypatch.undo()
+            self._clear(ops)

@@ -26,7 +26,6 @@ import numpy as np
 import jax, jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 from repro.core import sync as S
 from repro.core.compression import Level
 from repro.core.scheduler import SyncPlan
@@ -55,13 +54,13 @@ def inner(t, e):
                        inside_manual=True)
 
 
-smapped = compat.shard_map(
-    inner, mesh,
+smapped = jax.shard_map(
+    inner, mesh=mesh,
     in_specs=(jax.tree.map(lambda _: P(), tree),
               jax.tree.map(lambda _: P(), errors)),
     out_specs=(jax.tree.map(lambda _: P(), tree),
                jax.tree.map(lambda _: P(), errors)),
-    manual_axes=set(mesh.axis_names))
+    check_vma=False)
 fn = jax.jit(smapped)
 
 # --- run it: EF invariant survives the real multi-pod exchange ----------
@@ -120,7 +119,6 @@ import numpy as np
 import jax, jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 from repro.core import sync as S
 from repro.core.compression import Level
 from repro.core.planexec import build_exec_plan, sig_wire_bytes
@@ -154,13 +152,13 @@ def run(ep):
     def inner(t, e):
         return S.sync_tree(t, e, ep, mesh=mesh, shardings=None,
                            gamma=0.9, inside_manual=True)
-    smapped = compat.shard_map(
-        inner, mesh,
+    smapped = jax.shard_map(
+        inner, mesh=mesh,
         in_specs=(jax.tree.map(lambda _: P(), tree),
                   jax.tree.map(lambda _: P(), errors)),
         out_specs=(jax.tree.map(lambda _: P(), tree),
                    jax.tree.map(lambda _: P(), errors)),
-        manual_axes=set(mesh.axis_names))
+        check_vma=False)
     return jax.jit(smapped)
 
 
@@ -248,7 +246,6 @@ import numpy as np
 import jax, jax.numpy as jnp
 from jax.sharding import PartitionSpec as Spec
 
-from repro import compat
 from repro.core import sync as S
 from repro.core.compression import Level
 from repro.core.planexec import build_exec_plan, ring_hops, sig_wire_bytes
@@ -292,9 +289,8 @@ def runner(ep):
         return (jax.tree.map(lambda x: x[None], a),
                 jax.tree.map(lambda x: x[None], ne))
     pod = jax.tree.map(lambda _: Spec("pod"), tree)
-    smapped = compat.shard_map(inner, mesh, in_specs=(pod, pod),
-                               out_specs=(pod, pod),
-                               manual_axes=set(mesh.axis_names))
+    smapped = jax.shard_map(inner, mesh=mesh, in_specs=(pod, pod),
+                            out_specs=(pod, pod), check_vma=False)
     return jax.jit(smapped)
 
 
@@ -407,7 +403,6 @@ import numpy as np
 import jax, jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 from repro.core import sync as S
 from repro.core.compression import Level
 from repro.core.planexec import build_exec_plan
@@ -447,9 +442,8 @@ def make_fn(ep):
         return S.sync_tree(grads, es, ep, mesh=mesh, shardings=None,
                            gamma=1.0, inside_manual=True)
     pp = jax.tree.map(lambda _: P(), params)
-    smapped = compat.shard_map(inner, mesh, in_specs=(pp, pp, P()),
-                               out_specs=(pp, pp),
-                               manual_axes=set(mesh.axis_names))
+    smapped = jax.shard_map(inner, mesh=mesh, in_specs=(pp, pp, P()),
+                            out_specs=(pp, pp), check_vma=False)
     return jax.jit(smapped)
 
 

@@ -312,7 +312,6 @@ import numpy as np
 import jax, jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 from repro.core import planexec
 from repro.core import sync as S
 from repro.core.compression import Level
@@ -351,11 +350,11 @@ def inner(t, e, p):
 
 
 pspec = jax.tree.map(lambda _: P(), tree)
-smapped = compat.shard_map(
-    inner, mesh,
+smapped = jax.shard_map(
+    inner, mesh=mesh,
     in_specs=(pspec, pspec, jax.tree.map(lambda _: P(), ep)),
     out_specs=(pspec, pspec),
-    manual_axes=set(mesh.axis_names))
+    check_vma=False)
 fn = jax.jit(smapped)
 
 agg_a, err_a = fn(tree, errors, ep)

@@ -10,7 +10,8 @@ try:
 except ImportError:  # property tests skip, the rest of the module runs
     from hypothesis_stub import given, settings, st
 
-from repro.kernels import autotune, ops, ref
+from repro.kernels import ops, ref
+from repro.kernels.cases import kernel_cases, parity
 from repro.kernels.topk_compress import ef_topk_select, LANES
 from repro.kernels.quantize import (quantize_int8_fused, dequantize_int8,
                                     ef_int4_fused, unpack_nibbles)
@@ -190,8 +191,8 @@ class TestOpsWrappers:
                                    atol=float(np.asarray(s).max()) * 0.51)
 
 
-def _gather_case(nbp1, S, seed, special, rows):
-    """Block buffers + padded perm for the producer-fused gather kernels.
+def _gather_case(nbp1, S, seed, special):
+    """Block buffers + perm for the producer-fused gather kernels.
     ``special`` seeds a denormal row and an all-zero row (absmax == 0:
     the scale guard must hold); the last row is the zero row the sync
     path pads with."""
@@ -206,8 +207,22 @@ def _gather_case(nbp1, S, seed, special, rows):
     fb[-1] = 0.0
     eb[-1] = 0.0
     perm = r.randint(0, nbp1, size=S).astype(np.int32)
-    p2, _ = ops._pad_perm(jnp.asarray(perm), rows, nbp1 - 1)
-    return jnp.asarray(fb), jnp.asarray(eb), p2
+    return jnp.asarray(fb), jnp.asarray(eb), jnp.asarray(perm)
+
+
+def _gather_codec(codec):
+    """(kernel, ref.py oracle, keywords) of one producer-fused gather."""
+    from repro.kernels import quantize, sign, topk_compress
+    return {
+        "int8": (quantize.quantize_int8_gather,
+                 ref.quantize_int8_gather_ref, dict(gamma=0.9)),
+        "int4": (quantize.ef_int4_gather, ref.ef_int4_gather_ref,
+                 dict(gamma=0.7)),
+        "sign": (sign.ef_sign_gather, ref.ef_sign_gather_ref,
+                 dict(gamma=0.6)),
+        "topk": (topk_compress.ef_topk_gather, ref.ef_topk_gather_ref,
+                 dict(gamma=1.0, k=104)),
+    }[codec]
 
 
 class TestGatherKernels:
@@ -219,114 +234,105 @@ class TestGatherKernels:
     is the one the (always-jitted) sync path relies on."""
 
     @given(st.integers(2, 9), st.integers(1, 23),
-           st.integers(0, 10 ** 6), st.booleans(),
-           st.sampled_from((1, 2, 4, 8)))
+           st.integers(0, 10 ** 6), st.booleans())
     @settings(max_examples=12, deadline=None)
-    def test_int8_gather_bit_parity(self, nbp1, S, seed, special, rows):
+    def test_int8_gather_bit_parity(self, nbp1, S, seed, special):
         from repro.kernels.quantize import quantize_int8_gather
-        fb, eb, p2 = _gather_case(nbp1, S, seed, special, rows)
-        q, s, r = quantize_int8_gather(fb, eb, p2, gamma=0.9, rows=rows,
+        fb, eb, perm = _gather_case(nbp1, S, seed, special)
+        q, s, r = quantize_int8_gather(fb, eb, perm, gamma=0.9,
                                        interpret=True)
         q_r, s_r, r_r = jax.jit(
             lambda f, e, p: ref.quantize_int8_gather_ref(f, e, p,
                                                          gamma=0.9)
-        )(fb, eb, p2)
+        )(fb, eb, perm)
         np.testing.assert_array_equal(np.asarray(q), np.asarray(q_r))
         np.testing.assert_array_equal(np.asarray(s), np.asarray(s_r))
         np.testing.assert_array_equal(np.asarray(r), np.asarray(r_r))
 
     @given(st.integers(2, 9), st.integers(1, 23),
-           st.integers(0, 10 ** 6), st.booleans(),
-           st.sampled_from((1, 2, 4, 8)))
+           st.integers(0, 10 ** 6), st.booleans())
     @settings(max_examples=12, deadline=None)
-    def test_int4_gather_bit_parity(self, nbp1, S, seed, special, rows):
+    def test_int4_gather_bit_parity(self, nbp1, S, seed, special):
         from repro.kernels.quantize import ef_int4_gather
-        fb, eb, p2 = _gather_case(nbp1, S, seed, special, rows)
-        p, s, r = ef_int4_gather(fb, eb, p2, gamma=0.7, rows=rows,
+        fb, eb, perm = _gather_case(nbp1, S, seed, special)
+        p, s, r = ef_int4_gather(fb, eb, perm, gamma=0.7,
                                  interpret=True)
         p_r, s_r, r_r = jax.jit(
             lambda f, e, pm: ref.ef_int4_gather_ref(f, e, pm, gamma=0.7)
-        )(fb, eb, p2)
+        )(fb, eb, perm)
         np.testing.assert_array_equal(np.asarray(p), np.asarray(p_r))
         np.testing.assert_array_equal(np.asarray(s), np.asarray(s_r))
         np.testing.assert_array_equal(np.asarray(r), np.asarray(r_r))
 
     @given(st.integers(2, 9), st.integers(1, 23),
-           st.integers(0, 10 ** 6), st.booleans(),
-           st.sampled_from((1, 2, 4, 8)))
+           st.integers(0, 10 ** 6), st.booleans())
     @settings(max_examples=12, deadline=None)
-    def test_sign_gather_bit_parity(self, nbp1, S, seed, special, rows):
+    def test_sign_gather_bit_parity(self, nbp1, S, seed, special):
         from repro.kernels.sign import ef_sign_gather
-        fb, eb, p2 = _gather_case(nbp1, S, seed, special, rows)
-        sg, s, r = ef_sign_gather(fb, eb, p2, gamma=0.6, rows=rows,
+        fb, eb, perm = _gather_case(nbp1, S, seed, special)
+        sg, s, r = ef_sign_gather(fb, eb, perm, gamma=0.6,
                                   interpret=True)
         sg_r, s_r, r_r = jax.jit(
             lambda f, e, p: ref.ef_sign_gather_ref(f, e, p, gamma=0.6)
-        )(fb, eb, p2)
+        )(fb, eb, perm)
         np.testing.assert_array_equal(np.asarray(sg), np.asarray(sg_r))
         np.testing.assert_array_equal(np.asarray(s), np.asarray(s_r))
         np.testing.assert_array_equal(np.asarray(r), np.asarray(r_r))
 
     @given(st.integers(2, 9), st.integers(1, 23),
-           st.integers(0, 10 ** 6), st.booleans(),
-           st.sampled_from((1, 2, 4, 8)))
+           st.integers(0, 10 ** 6), st.booleans())
     @settings(max_examples=12, deadline=None)
-    def test_topk_gather_bit_parity(self, nbp1, S, seed, special, rows):
+    def test_topk_gather_bit_parity(self, nbp1, S, seed, special):
         from repro.kernels.topk_compress import ef_topk_gather
-        fb, eb, p2 = _gather_case(nbp1, S, seed, special, rows)
-        sel, res = ef_topk_gather(fb, eb, p2, gamma=1.0, k=104,
-                                  rows=rows, interpret=True)
+        fb, eb, perm = _gather_case(nbp1, S, seed, special)
+        sel, res = ef_topk_gather(fb, eb, perm, gamma=1.0, k=104,
+                                  interpret=True)
         sel_r, res_r = jax.jit(
             lambda f, e, p: ref.ef_topk_gather_ref(f, e, p, gamma=1.0,
                                                    k=104)
-        )(fb, eb, p2)
+        )(fb, eb, perm)
         np.testing.assert_array_equal(np.asarray(sel), np.asarray(sel_r))
         np.testing.assert_array_equal(np.asarray(res), np.asarray(res_r))
 
     # Deterministic sweep over the same case space — runs even where
     # hypothesis is absent (the property tests then skip via the stub).
-    @pytest.mark.parametrize("rows", [1, 2, 4, 8])
+    @pytest.mark.parametrize("codec", ["int8", "int4", "sign", "topk"])
     @pytest.mark.parametrize("special", [False, True])
-    def test_gather_bit_parity_grid(self, rows, special):
-        from repro.kernels.quantize import (ef_int4_gather,
-                                            quantize_int8_gather)
-        from repro.kernels.sign import ef_sign_gather
-        from repro.kernels.topk_compress import ef_topk_gather
+    def test_gather_bit_parity_grid(self, codec, special):
+        kern, oracle, kw = _gather_codec(codec)
         for nbp1, S, seed in [(2, 1, 0), (5, 7, 1), (9, 23, 2),
                               (6, 13, 3)]:
-            fb, eb, p2 = _gather_case(nbp1, S, seed, special, rows)
-            pairs = [
-                (quantize_int8_gather(fb, eb, p2, gamma=0.9, rows=rows,
-                                      interpret=True),
-                 jax.jit(lambda f, e, p: ref.quantize_int8_gather_ref(
-                     f, e, p, gamma=0.9))(fb, eb, p2)),
-                (ef_int4_gather(fb, eb, p2, gamma=0.7, rows=rows,
-                                interpret=True),
-                 jax.jit(lambda f, e, p: ref.ef_int4_gather_ref(
-                     f, e, p, gamma=0.7))(fb, eb, p2)),
-                (ef_sign_gather(fb, eb, p2, gamma=0.6, rows=rows,
-                                interpret=True),
-                 jax.jit(lambda f, e, p: ref.ef_sign_gather_ref(
-                     f, e, p, gamma=0.6))(fb, eb, p2)),
-                (ef_topk_gather(fb, eb, p2, gamma=1.0, k=104, rows=rows,
-                                interpret=True),
-                 jax.jit(lambda f, e, p: ref.ef_topk_gather_ref(
-                     f, e, p, gamma=1.0, k=104))(fb, eb, p2)),
-            ]
-            for got, want in pairs:
-                for a, b in zip(got, want):
-                    np.testing.assert_array_equal(np.asarray(a),
-                                                  np.asarray(b))
+            fb, eb, perm = _gather_case(nbp1, S, seed, special)
+            got = kern(fb, eb, perm, interpret=True, **kw)
+            want = jax.jit(lambda f, e, p: oracle(f, e, p, **kw))(
+                fb, eb, perm)
+            for a, b in zip(got, want):
+                np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+    @pytest.mark.parametrize("codec", ["int8", "int4", "sign", "topk"])
+    def test_chunked_gather_bit_parity(self, codec, monkeypatch):
+        """A perm longer than one call's scalar memory runs as several
+        calls writing in place into the shared outputs: same bits as one
+        call."""
+        import functools
+        from repro.kernels import topk_compress
+        monkeypatch.setattr(topk_compress, "MAX_GATHER_ROWS", 5)
+        fn, oracle, kw = _gather_codec(codec)
+        fb, eb, perm = _gather_case(10, 13, 4, True)
+        # a fresh jit: the module-level one may hold an unchunked trace
+        got = jax.jit(functools.partial(fn.__wrapped__, interpret=True,
+                                        **kw))(fb, eb, perm)
+        want = jax.jit(lambda f, e, p: oracle(f, e, p, **kw))(fb, eb, perm)
+        for a, b in zip(got, want):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
     @given(st.integers(2, 9), st.integers(1, 23),
            st.integers(0, 10 ** 6))
     @settings(max_examples=10, deadline=None)
     def test_ops_wrapper_slices_to_perm_length(self, nbp1, S, seed):
-        """The ops.gather_ef_* wrappers pad the perm to the autotuned
-        tile height and slice back: outputs are (S, ...) and match the
-        oracle on the ORIGINAL perm bit for bit."""
-        fb, eb, p2 = _gather_case(nbp1, S, seed, False, 1)
-        perm = p2[:S]
+        """The ops.gather_ef_* wrappers return (S, ...) outputs that match
+        the oracle on the perm bit for bit."""
+        fb, eb, perm = _gather_case(nbp1, S, seed, False)
         q, s, r = ops.gather_ef_int8(fb, eb, perm, gamma=0.9,
                                      use_pallas=True)
         assert q.shape == (S, LANES) and r.shape == (S * LANES,)
@@ -340,78 +346,14 @@ class TestGatherKernels:
                                       np.asarray(r_r).reshape(-1))
 
 
-class TestAutotune:
-    """The block-size autotuner's determinism contract
-    (tests satellite: REPRO_FORCE_INTERPRET must force the deterministic
-    default path and never touch the cache file)."""
+# Every kernel of the shared case table, interpreted, against its jitted
+# oracle: the same check the chip smoke test makes on the device at the
+# real bucket size.
+CASES = {c.name: c for c in kernel_cases(rows=16, nb=20, k=104)}
 
-    def _reset(self):
-        ops.interpret_mode.cache_clear()
-        ops.default_use_pallas.cache_clear()
-        autotune.clear_memo()
 
-    @pytest.fixture(autouse=True)
-    def _isolate(self, tmp_path, monkeypatch):
-        monkeypatch.setenv(autotune.CACHE_ENV,
-                           str(tmp_path / "autotune.json"))
-        self.cache = tmp_path / "autotune.json"
-        self._reset()
-        yield
-        self._reset()
-
-    def test_interpret_mode_default_rows_no_cache_write(self, monkeypatch):
-        monkeypatch.setenv(ops.FORCE_INTERPRET_ENV, "1")
-        self._reset()
-        for codec in ("int8", "int4", "sign", "topk"):
-            for n in (1, 5, 64, 1000):
-                assert autotune.block_rows(codec, n) == \
-                    autotune.DEFAULT_ROWS
-        # drive the real producer-fused path end to end
-        fb = jnp.asarray(np.random.RandomState(0)
-                         .randn(4, LANES).astype(np.float32))
-        eb = fb * 0.5
-        perm = jnp.arange(3, dtype=jnp.int32)
-        out = ops.gather_ef_int8(fb, eb, perm, gamma=1.0, use_pallas=True)
-        jax.block_until_ready(out)
-        assert not self.cache.exists(), \
-            "interpret mode must never write the autotune cache"
-
-    def test_measured_path_caches_to_disk(self, monkeypatch):
-        monkeypatch.setenv(ops.FORCE_INTERPRET_ENV, "0")
-        self._reset()
-        calls = []
-
-        def bench(rows):
-            calls.append(rows)
-            return 1.0 / rows  # taller tiles win
-        assert autotune.block_rows("int8", 64, bench=bench) == 8
-        assert calls == [1, 2, 4, 8]
-        assert self.cache.exists()
-        # memo hit: no re-measure
-        calls.clear()
-        assert autotune.block_rows("int8", 64, bench=bench) == 8
-        assert calls == []
-        # fresh process (memo cleared): served from disk, still no bench
-        autotune.clear_memo()
-        assert autotune.block_rows("int8", 64, bench=bench) == 8
-        assert calls == []
-        # same sig class shares the entry; a different class re-measures
-        assert autotune.block_rows("int8", 50, bench=bench) == 8
-        assert calls == []
-        assert autotune.block_rows("int8", 3, bench=bench) == 2
-        assert calls == [1, 2]  # candidates capped at n_rows
-
-    def test_candidates_capped_and_failures_skipped(self, monkeypatch):
-        monkeypatch.setenv(ops.FORCE_INTERPRET_ENV, "0")
-        self._reset()
-
-        def bench(rows):
-            if rows > 2:
-                raise RuntimeError("tile too tall for vmem")
-            return float(rows)
-        assert autotune.block_rows("sign", 64, bench=bench) == 1
-        # no bench at all: deterministic default, nothing persisted
-        autotune.clear_memo()
-        self.cache.unlink()
-        assert autotune.block_rows("topk", 64) == autotune.DEFAULT_ROWS
-        assert not self.cache.exists()
+@pytest.mark.parametrize("name", sorted(CASES))
+def test_case_table_bit_parity(name):
+    case = CASES[name]
+    out = parity(case, case.inputs(seed=3), interpret=True)
+    assert out == {"mismatches": 0, "max_abs_diff": 0.0}, (name, out)

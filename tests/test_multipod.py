@@ -124,12 +124,15 @@ print("P3_SOAK_OK")
 # soak with overlap_backward on vs off must land BIT-identical params on
 # every pod (the segment split is numerics-neutral by blockwise codec
 # math; anything else is a streaming bug).  The contract is pinned on
-# the kernel path (REPRO_FORCE_INTERPRET=1, matching CI): on the pure-
-# jnp oracle path XLA:CPU fuses the whole step program and its FMA
-# contraction follows the program shape, so the differently-segmented
-# on/off programs pick up ulp-level noise OUTSIDE the sync region —
-# sync_tree itself is bit-exact seg-vs-flat even with nonzero error
-# buffers (pinned in tests/test_collectives.py).  Parameterised via env
+# the kernel path (REPRO_FORCE_INTERPRET=1, matching CI) with FMA
+# instructions off (--xla_cpu_max_isa=AVX): XLA:CPU contracts a*b + c
+# into one FMA wherever its fusion puts the multiply and the add in one
+# loop, so the differently-segmented on/off programs would round the
+# rung-ordered AdamW's moment updates (beta*m + (1-beta)*g) differently
+# at the ulp level.  Without FMA every op rounds on its own and the
+# result is a function of the math alone — sync_tree itself is bit-exact
+# seg-vs-flat even with nonzero error buffers (pinned in
+# tests/test_collectives.py).  Parameterised via env
 # vars like tests/test_collectives.py's DET_SCRIPT (XLA locks the device
 # count per process).  The companion retrace contract — zero steady-state
 # recompiles across replans that change the rung schedule, including
@@ -138,7 +141,8 @@ OVERLAP_SOAK_SCRIPT = r"""
 import os
 MESH = tuple(int(x) for x in os.environ["REPRO_TEST_MESH"].split(","))
 os.environ["XLA_FLAGS"] = ("--xla_force_host_platform_device_count="
-                           + os.environ["REPRO_TEST_DEVS"])
+                           + os.environ["REPRO_TEST_DEVS"]
+                           + " --xla_cpu_max_isa=AVX")
 import jax
 import numpy as np
 from repro.configs import SMOKE_ARCHS
