@@ -1,0 +1,15 @@
+"""The benchmark's own CPU tests: run by hand from the repository root,
+
+    JAX_PLATFORMS=cpu python -m pytest -q chipbench/tests
+
+They import the harness modules from ``chipbench/`` and the program from
+``src/``."""
+import os
+import sys
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+BENCH = os.path.dirname(HERE)
+ROOT = os.path.dirname(BENCH)
+for p in (BENCH, os.path.join(ROOT, "src")):
+    if p not in sys.path:
+        sys.path.insert(0, p)
