@@ -73,35 +73,41 @@ def sync_gradients(grads, state: ACEState, plan: Union[SyncPlan, ExecPlan],
     aggregate is consumed rung by rung — the first return value is then
     the tuple of updated ``apply_aux`` trees instead of the aggregated
     gradients, and the optimizer work overlaps the later rungs'
-    exchanges."""
-    # --- per-group stats for the importance estimator ---
-    mean_abs, var, nrm = S.grad_group_stats(grads)
-    if S._pod_info(mesh) > 1:
-        # one fleet collective for all three (G,) stat vectors — stacked,
-        # a single pmean reduces each element exactly as three would
-        axes = S.fleet_axes(mesh)
-        mean_abs, var, nrm = jax.lax.pmean(
-            jnp.stack([mean_abs, var, nrm]), axes)
-    ist = imp.update_stats(state.importance, mean_abs, var, nrm)
-    # online supervision: the observed (normalised) gradient-norm momentum is
-    # the ground-truth importance signal for this window
-    target = ist.norm_mom / jnp.maximum(jnp.max(ist.norm_mom), 1e-12)
-    ist, mse = imp.train_step(ist, state.struct_feat, target,
-                              alpha=cfg.alpha, lr=cfg.importance_lr)
+    exchanges.  The whole round runs under the ``exchange`` named scope
+    (``importance`` for the estimator's stats and step; ``core/sync.py``
+    names the rest)."""
+    with jax.named_scope("exchange"):
+        # --- per-group stats for the importance estimator ---
+        with jax.named_scope("importance"):
+            mean_abs, var, nrm = S.grad_group_stats(grads)
+            if S._pod_info(mesh) > 1:
+                # one fleet collective for all three (G,) stat vectors —
+                # stacked, a single pmean reduces each element exactly as
+                # three would
+                axes = S.fleet_axes(mesh)
+                mean_abs, var, nrm = jax.lax.pmean(
+                    jnp.stack([mean_abs, var, nrm]), axes)
+            ist = imp.update_stats(state.importance, mean_abs, var, nrm)
+            # online supervision: the observed (normalised) gradient-norm
+            # momentum is the ground-truth importance signal for this
+            # window
+            target = ist.norm_mom / jnp.maximum(jnp.max(ist.norm_mom), 1e-12)
+            ist, mse = imp.train_step(ist, state.struct_feat, target,
+                                      alpha=cfg.alpha, lr=cfg.importance_lr)
 
-    # --- error feedback + compression + pod aggregation ---
-    agg, new_errors = S.sync_tree(grads, state.errors, plan, mesh=mesh,
-                                  shardings=shardings, gamma=cfg.gamma,
-                                  block=cfg.topk_block,
-                                  bidir=cfg.ring_bidir,
-                                  fixed_bits=cfg.accum_bits,
-                                  apply_fn=apply_fn,
-                                  apply_aux=apply_aux,
-                                  apply_scalars=apply_scalars)
+        # --- error feedback + compression + pod aggregation ---
+        agg, new_errors = S.sync_tree(grads, state.errors, plan, mesh=mesh,
+                                      shardings=shardings, gamma=cfg.gamma,
+                                      block=cfg.topk_block,
+                                      bidir=cfg.ring_bidir,
+                                      fixed_bits=cfg.accum_bits,
+                                      apply_fn=apply_fn,
+                                      apply_aux=apply_aux,
+                                      apply_scalars=apply_scalars)
 
-    new_state = state._replace(errors=new_errors, importance=ist,
-                               mse_ema=0.99 * state.mse_ema + 0.01 * mse)
-    metrics = {"imp_mse": mse, "grad_norm_mean": jnp.mean(nrm)}
+        new_state = state._replace(errors=new_errors, importance=ist,
+                                   mse_ema=0.99 * state.mse_ema + 0.01 * mse)
+        metrics = {"imp_mse": mse, "grad_norm_mean": jnp.mean(nrm)}
     return agg, new_state, metrics
 
 

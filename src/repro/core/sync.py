@@ -258,18 +258,24 @@ def _range_sync(gs, es, aux, perms, sig, chunks, hgrid, NB, *, levels,
     collectives below carry no data dependence on any other segment's
     gradients and XLA's scheduler issues them while the rest of the
     backward still runs.  Returns ``(aggs | aux_outs, errs)`` as leaf
-    tuples for the range."""
-    fb = _leaf_blocks(gs, block)
-    eb = _leaf_blocks(es, block)
-    assert fb.shape[0] == NB, \
-        f"leaf layout has {fb.shape[0]} blocks, plan was built for {NB}"
-    zrow = jnp.zeros((1, block), jnp.float32)
-    fb = jnp.concatenate([fb, zrow])
-    eb = jnp.concatenate([eb, zrow])
-    abufs = [jnp.concatenate([_leaf_blocks(a, block), zrow]) for a in aux]
-    agg = None if apply_fn is not None \
-        else jnp.zeros((NB + 1, block), jnp.float32)
-    err = jnp.zeros((NB + 1, block), jnp.float32)
+    tuples for the range.
+
+    Named scopes (inside the caller's ``exchange``): ``pack``, one
+    ``encode_<RUNG>`` per rung, ``collective``, ``decode``, ``scatter``
+    and ``unpack``."""
+    with jax.named_scope("pack"):
+        fb = _leaf_blocks(gs, block)
+        eb = _leaf_blocks(es, block)
+        assert fb.shape[0] == NB, \
+            f"leaf layout has {fb.shape[0]} blocks, plan was built for {NB}"
+        zrow = jnp.zeros((1, block), jnp.float32)
+        fb = jnp.concatenate([fb, zrow])
+        eb = jnp.concatenate([eb, zrow])
+        abufs = [jnp.concatenate([_leaf_blocks(a, block), zrow])
+                 for a in aux]
+        agg = None if apply_fn is not None \
+            else jnp.zeros((NB + 1, block), jnp.float32)
+        err = jnp.zeros((NB + 1, block), jnp.float32)
     # Encode pass: every payload-gather rung (one-shot multi-pod path)
     # stops at its packed uint8 wire buffer; the wires are concatenated
     # into ONE all_gather per range instead of one per rung — same bytes,
@@ -289,48 +295,55 @@ def _range_sync(gs, es, aux, perms, sig, chunks, hgrid, NB, *, levels,
         codec = levels[r].codec
         chunks_r = chunks[r] if chunks else 0
         hier_r = hgrid[r] if hgrid else 0
-        if (n_pods > 1 and codec.supports_ring
-                and not (hier_r and n_edge > 1)
-                and not (chunks_r and n_pods > 1)):
-            wire, meta, new_e = codec.ef_encode_wire(
-                fb, eb, perm, gamma=gamma, block=block,
-                use_pallas=use_pallas)
-            staged.append((S, perm, codec, (meta, woff, wire.shape[0],
-                                            new_e)))
-            wire_parts.append(wire)
-            woff += wire.shape[0]
-        else:
-            b_out = _rung_exchange(
-                codec, fb, eb, perm, omega,
-                omega_own, chunks=chunks_r,
-                bidir=bidir, gamma=gamma, n_pods=n_pods, block=block,
-                use_pallas=use_pallas, fixed_bits=fixed_bits,
-                hier=hier_r, n_cross=n_cross,
-                n_edge=n_edge, omega_intra=omega_intra)
-            staged.append((S, perm, None, b_out))
+        # a rung's whole round where it has its own exchange path (one
+        # pod, ring, two-tier): decode and collective included
+        with jax.named_scope("encode_" + levels[r].name):
+            if (n_pods > 1 and codec.supports_ring
+                    and not (hier_r and n_edge > 1)
+                    and not (chunks_r and n_pods > 1)):
+                wire, meta, new_e = codec.ef_encode_wire(
+                    fb, eb, perm, gamma=gamma, block=block,
+                    use_pallas=use_pallas)
+                staged.append((S, perm, codec, (meta, woff, wire.shape[0],
+                                                new_e)))
+                wire_parts.append(wire)
+                woff += wire.shape[0]
+            else:
+                b_out = _rung_exchange(
+                    codec, fb, eb, perm, omega,
+                    omega_own, chunks=chunks_r,
+                    bidir=bidir, gamma=gamma, n_pods=n_pods, block=block,
+                    use_pallas=use_pallas, fixed_bits=fixed_bits,
+                    hier=hier_r, n_cross=n_cross,
+                    n_edge=n_edge, omega_intra=omega_intra)
+                staged.append((S, perm, None, b_out))
     gathered = None
     if wire_parts:
-        coal = wire_parts[0] if len(wire_parts) == 1 \
-            else jnp.concatenate(wire_parts)
-        gathered = jax.lax.all_gather(coal, axis)
+        with jax.named_scope("collective"):
+            coal = wire_parts[0] if len(wire_parts) == 1 \
+                else jnp.concatenate(wire_parts)
+            gathered = jax.lax.all_gather(coal, axis)
     # Decode + scatter pass, in rung order (the perms are disjoint).
     for S, perm, codec, payload in staged:
         if codec is None:
             b_agg, b_err = payload
         else:
             meta, o, nbytes, b_err = payload
-            b_agg = codec.wire_decode_fold(
-                gathered[:, o:o + nbytes], meta, omega, n=S * block,
-                block=block, use_pallas=use_pallas,
-                deterministic=n_pods >= 3, fixed_bits=fixed_bits)
-        err = err.at[perm].set(b_err.reshape(S, block))
-        if apply_fn is None:
-            agg = agg.at[perm].set(b_agg.reshape(S, block))
-        else:
-            rows = apply_fn(b_agg.reshape(S, block),
-                            tuple(ab[perm] for ab in abufs), scalars)
-            abufs = [ab.at[perm].set(nr)
-                     for ab, nr in zip(abufs, rows)]
+            with jax.named_scope("decode"):
+                b_agg = codec.wire_decode_fold(
+                    gathered[:, o:o + nbytes], meta, omega, n=S * block,
+                    block=block, use_pallas=use_pallas,
+                    deterministic=n_pods >= 3, fixed_bits=fixed_bits)
+        # apply_fn names its own scope (the trainer's ``optimizer``)
+        with jax.named_scope("scatter"):
+            err = err.at[perm].set(b_err.reshape(S, block))
+            if apply_fn is None:
+                agg = agg.at[perm].set(b_agg.reshape(S, block))
+            else:
+                rows = apply_fn(b_agg.reshape(S, block),
+                                tuple(ab[perm] for ab in abufs), scalars)
+                abufs = [ab.at[perm].set(nr)
+                         for ab, nr in zip(abufs, rows)]
 
     def unpack(flat_buf, like):
         outs, boff = [], 0
@@ -342,11 +355,12 @@ def _range_sync(gs, es, aux, perms, sig, chunks, hgrid, NB, *, levels,
             boff += n_blocks(n, block)
         return tuple(outs)
 
-    errs = unpack(err[:NB].reshape(-1), es)
-    if apply_fn is None:
-        return unpack(agg[:NB].reshape(-1), gs), errs
-    outs = tuple(unpack(ab[:NB].reshape(-1), a)
-                 for ab, a in zip(abufs, aux))
+    with jax.named_scope("unpack"):
+        errs = unpack(err[:NB].reshape(-1), es)
+        if apply_fn is None:
+            return unpack(agg[:NB].reshape(-1), gs), errs
+        outs = tuple(unpack(ab[:NB].reshape(-1), a)
+                     for ab, a in zip(abufs, aux))
     return outs, errs
 
 

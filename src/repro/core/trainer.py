@@ -42,6 +42,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from repro import obs
 from repro.configs.base import RunConfig
 from repro.core import acesync
 from repro.core import planexec
@@ -218,30 +219,36 @@ class Trainer:
         run = self.run
 
         def loss_fn(p):
-            return self.model.loss(p, batch)
+            # the backward pass is this scope's transpose:
+            # transpose(jvp(forward)) in the ops' op_name
+            with jax.named_scope("forward"):
+                return self.model.loss(p, batch)
 
         loss, grads = jax.value_and_grad(loss_fn)(params)
-        if run.grad_clip > 0:
-            grads, gnorm = adamw.clip_by_global_norm(grads, run.grad_clip)
-        else:
-            # grad_clip <= 0 disables clipping.  The global-norm scale
-            # couples every grad leaf to the whole backward pass, which
-            # serializes the backward-interleaved exchange: no segment's
-            # collective can issue before the last backward op.  The norm
-            # itself is still recorded (metrics only — outputs never gate
-            # the rung collectives).
-            gnorm = adamw.global_norm(grads)
+        with jax.named_scope("optimizer"):
+            if run.grad_clip > 0:
+                grads, gnorm = adamw.clip_by_global_norm(grads,
+                                                         run.grad_clip)
+            else:
+                # grad_clip <= 0 disables clipping.  The global-norm scale
+                # couples every grad leaf to the whole backward pass,
+                # which serializes the backward-interleaved exchange: no
+                # segment's collective can issue before the last backward
+                # op.  The norm itself is still recorded (metrics only —
+                # outputs never gate the rung collectives).
+                gnorm = adamw.global_norm(grads)
         return loss, grads, gnorm
 
     def _optimize(self, params, grads, m, v, step):
         run = self.run
-        lr = adamw.cosine_schedule(step, base_lr=run.lr,
-                                   warmup=run.warmup_steps,
-                                   total=run.total_steps)
-        new_params, opt = adamw.adamw_update(
-            params, grads, {"m": m, "v": v}, step, lr=lr,
-            beta1=run.beta1, beta2=run.beta2, weight_decay=run.weight_decay)
-        return new_params, opt
+        with jax.named_scope("optimizer"):
+            lr = adamw.cosine_schedule(step, base_lr=run.lr,
+                                       warmup=run.warmup_steps,
+                                       total=run.total_steps)
+            return adamw.adamw_update(
+                params, grads, {"m": m, "v": v}, step, lr=lr,
+                beta1=run.beta1, beta2=run.beta2,
+                weight_decay=run.weight_decay)
 
     def _body_grad_sync(self, state, batch, plan: ExecPlan):
         st = self._split_pod(state)
@@ -254,19 +261,21 @@ class Trainer:
             # behind the next rung's DCN transfer instead of waiting on a
             # whole-tree barrier after sync_tree.  Same elementwise math
             # as _optimize, on the exchange's (S, block) f32 rows.
-            lr = adamw.cosine_schedule(st["step"], base_lr=run.lr,
-                                       warmup=run.warmup_steps,
-                                       total=run.total_steps)
-            bc1, bc2 = adamw.bias_corrections(st["step"], run.beta1,
-                                              run.beta2)
+            with jax.named_scope("optimizer"):
+                lr = adamw.cosine_schedule(st["step"], base_lr=run.lr,
+                                           warmup=run.warmup_steps,
+                                           total=run.total_steps)
+                bc1, bc2 = adamw.bias_corrections(st["step"], run.beta1,
+                                                  run.beta2)
 
             def apply_rows(g_rows, aux_rows, scalars):
                 p, m, v = aux_rows
                 lr_s, bc1_s, bc2_s = scalars
-                return adamw.update_rows(
-                    p, g_rows, m, v, lr=lr_s, bc1=bc1_s, bc2=bc2_s,
-                    beta1=run.beta1, beta2=run.beta2,
-                    weight_decay=run.weight_decay)
+                with jax.named_scope("optimizer"):
+                    return adamw.update_rows(
+                        p, g_rows, m, v, lr=lr_s, bc1=bc1_s, bc2=bc2_s,
+                        beta1=run.beta1, beta2=run.beta2,
+                        weight_decay=run.weight_decay)
 
             out, new_ace, metrics = acesync.sync_gradients(
                 grads, st["ace"], plan, mesh=self.mesh,
@@ -310,13 +319,15 @@ class Trainer:
         math of rung r hides behind rung r+1's DCN transfer instead of
         barriering on the whole tree."""
         st = self._split_pod(state)
-        delta = jax.tree.map(lambda p, a: (p - a).astype(p.dtype),
-                             st["params"], st["anchor"])
-        div = D.pod_divergence(st["params"], self.mesh)
+        with jax.named_scope("exchange"):
+            delta = jax.tree.map(lambda p, a: (p - a).astype(p.dtype),
+                                 st["params"], st["anchor"])
+            div = D.pod_divergence(st["params"], self.mesh)
         if self.run.acesync.overlap_apply:
             def apply_anchor(d_rows, aux_rows, _scalars):
                 (a_rows,) = aux_rows
-                return (a_rows + d_rows,)
+                with jax.named_scope("optimizer"):
+                    return (a_rows + d_rows,)
 
             out, new_ace, metrics = acesync.sync_gradients(
                 delta, st["ace"], plan, mesh=self.mesh,
@@ -327,8 +338,9 @@ class Trainer:
             agg, new_ace, metrics = acesync.sync_gradients(
                 delta, st["ace"], plan, mesh=self.mesh,
                 shardings=self.param_shardings, cfg=self.run.acesync)
-            new_params = jax.tree.map(lambda a, d: (a + d).astype(a.dtype),
-                                      st["anchor"], agg)
+            with jax.named_scope("optimizer"):
+                new_params = jax.tree.map(
+                    lambda a, d: (a + d).astype(a.dtype), st["anchor"], agg)
         new_ace = new_ace._replace(
             div_ema=0.9 * st["ace"].div_ema + 0.1 * self._pmean(div))
         new_st = dict(st, params=new_params,
@@ -517,7 +529,20 @@ class Trainer:
                 # after dispatch, when the donated buffers are already
                 # gone) propagates — re-running would only mask it.
                 self._aot_cache.pop(key, None)
-        return self.jit_step(ep, kind)(state, batch, ep)
+        fn = self.jit_step(ep, kind)
+        if not obs.enabled():
+            return fn(state, batch, ep)
+        # traced: every dispatch that compiled is counted; one into an
+        # empty jit cache (the usual compile) is a span of its own
+        cached = self._fn_cache_size(fn)
+        if cached:
+            out = fn(state, batch, ep)
+        else:
+            with obs.span("trainer.compile"):
+                out = fn(state, batch, ep)
+        if self._fn_cache_size(fn) > cached:
+            obs.count("step.compiles")
+        return out
 
     def step_fn(self, plan: Union[SyncPlan, ExecPlan],
                 kind: str = "grad_sync") -> Callable:
@@ -584,6 +609,10 @@ class Trainer:
         BEFORE swapping the plan in and a class-ladder rung change never
         stalls the device on a foreground compile (ROADMAP follow-up).
         Returns True when every requested kind is warm afterwards."""
+        with obs.span("trainer.warm_compile"):
+            return self._warm_compile(plan, kinds)
+
+    def _warm_compile(self, plan, kinds) -> bool:
         ep = self.exec_plan(plan)
         ok = True
         for kind in (kinds if kinds is not None else tuple(self._arg_specs)):
@@ -613,6 +642,21 @@ class Trainer:
                 self._aot_cache[key] = compiled
             self.warm_compiles += 1
         return ok
+
+    def step_hlo_text(self, plan: Union[SyncPlan, ExecPlan],
+                      kind: str = "grad_sync") -> str:
+        """The optimised HLO text of the step executable for ``plan``,
+        compiled against the recorded argument specs as
+        :meth:`warm_compile` does (a persistent-cache hit once the step
+        ran).  Each instruction's ``op_name`` carries the step's named
+        scopes: ``forward``, its transpose (the backward pass),
+        ``optimizer`` and ``exchange``."""
+        ep = self.exec_plan(plan)
+        specs = self._arg_specs.get(kind)
+        if specs is None:
+            raise ValueError(f"no {kind!r} step has run: nothing to lower")
+        return self.jit_step(ep, kind).lower(
+            specs[0], specs[1], self.plan_arg_specs(ep)).compile().as_text()
 
     # convenience plans per strategy ------------------------------------
     def default_plan(self, importance=None, bandwidth_mbps: float = 50.0,

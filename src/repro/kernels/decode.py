@@ -106,6 +106,7 @@ def dequant_accum_int8_fused(acc, q, s, w, *, interpret: bool = False):
     assert lanes == LANES and n_rows % ROWS == 0, (acc.shape,)
     return pl.pallas_call(
         _int8_kernel,
+        name="dequant_accum_int8_fused",
         grid=(n_rows // ROWS,),
         in_specs=[_spec, _spec, _sspec, _wspec],
         out_specs=_spec,
@@ -129,6 +130,7 @@ def dequant_accum_int4_fused(acc, p, s, w, *, interpret: bool = False):
     pspec = pl.BlockSpec((ROWS, LANES // 2), lambda i: (i, 0))
     return pl.pallas_call(
         _int4_kernel,
+        name="dequant_accum_int4_fused",
         grid=(n_rows // ROWS,),
         in_specs=[_spec, pspec, _sspec, _wspec],
         out_specs=_spec,
@@ -154,6 +156,7 @@ def sign_vote_accum_fused(vote, mag, p, s, w, *, interpret: bool = False):
     pspec = pl.BlockSpec((ROWS, LANES // 8), lambda i: (i, 0))
     return pl.pallas_call(
         _sign_kernel,
+        name="sign_vote_accum_fused",
         grid=(n_rows // ROWS,),
         in_specs=[_spec, _sspec, pspec, _sspec, _wspec],
         out_specs=[_spec, _sspec],
@@ -200,6 +203,7 @@ def dequant_accum_int8_fp_fused(acc, q, s, w, *, bits: int,
     assert lanes == LANES and n_rows % ROWS == 0, (acc.shape,)
     return pl.pallas_call(
         functools.partial(_int8_fp_kernel, bits=bits),
+        name="dequant_accum_int8_fp_fused",
         grid=(n_rows // ROWS,),
         in_specs=[_spec, _spec, _sspec, _wspec],
         out_specs=_spec,
@@ -224,6 +228,7 @@ def dequant_accum_int4_fp_fused(acc, p, s, w, *, bits: int,
     pspec = pl.BlockSpec((ROWS, LANES // 2), lambda i: (i, 0))
     return pl.pallas_call(
         functools.partial(_int4_fp_kernel, bits=bits),
+        name="dequant_accum_int4_fp_fused",
         grid=(n_rows // ROWS,),
         in_specs=[_spec, pspec, _sspec, _wspec],
         out_specs=_spec,
@@ -252,6 +257,7 @@ def sign_vote_accum_fp_fused(vote, mag, p, s, w, *, bits: int,
     pspec = pl.BlockSpec((ROWS, LANES // 8), lambda i: (i, 0))
     return pl.pallas_call(
         functools.partial(_sign_fp_kernel, bits=bits),
+        name="sign_vote_accum_fp_fused",
         grid=(n_rows // ROWS,),
         in_specs=[_spec, _sspec, pspec, _sspec, _wspec],
         out_specs=[_spec, _sspec],
@@ -272,6 +278,7 @@ def topk_scatter_accum_fused(acc, q, idx, s, w, *, interpret: bool = False):
     kspec = pl.BlockSpec((ROWS, k), lambda i: (i, 0))
     return pl.pallas_call(
         functools.partial(_topk_kernel, k=k),
+        name="topk_scatter_accum_fused",
         grid=(n_rows // ROWS,),
         in_specs=[_spec, kspec, kspec, _sspec, _wspec],
         out_specs=_spec,

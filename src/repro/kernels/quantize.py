@@ -54,6 +54,7 @@ def quantize_int8_fused(x, *, interpret: bool = False):
         _kernel,
         grid=grid,
         in_specs=[spec],
+        name="quantize_int8_fused",
         out_specs=[spec, sspec, spec],
         out_shape=[
             jax.ShapeDtypeStruct((n_rows, LANES), jnp.int8),
@@ -80,7 +81,8 @@ def quantize_int8_gather(fb, eb, perm, *, gamma: float,
         return q, scale, ef - q * scale
 
     out_defs = [(LANES, jnp.int8), (1, jnp.float32), (LANES, jnp.float32)]
-    return gather_ef_call(body, fb, eb, perm, out_defs, interpret=interpret)
+    return gather_ef_call(body, fb, eb, perm, out_defs,
+                          name="quantize_int8_gather", interpret=interpret)
 
 
 # ---------------------------------------------------------------------------
@@ -137,6 +139,7 @@ def ef_int4_fused(g, e, *, gamma: float, interpret: bool = False):
     sspec = pl.BlockSpec((ROWS, 1), lambda i: (i, 0))
     p, s, r = pl.pallas_call(
         functools.partial(_int4_kernel, gamma=gamma),
+        name="ef_int4_fused",
         grid=grid,
         in_specs=[spec, spec],
         out_specs=[pspec, sspec, spec],
@@ -164,7 +167,8 @@ def ef_int4_gather(fb, eb, perm, *, gamma: float,
 
     out_defs = [(LANES // 2, jnp.uint8), (1, jnp.float32),
                 (LANES, jnp.float32)]
-    return gather_ef_call(body, fb, eb, perm, out_defs, interpret=interpret)
+    return gather_ef_call(body, fb, eb, perm, out_defs,
+                          name="ef_int4_gather", interpret=interpret)
 
 
 def _dequant_kernel(q_ref, s_ref, out_ref):
@@ -179,6 +183,7 @@ def dequantize_int8(q, scales, *, interpret: bool = False):
     grid = (n_rows // ROWS,)
     return pl.pallas_call(
         _dequant_kernel,
+        name="dequantize_int8",
         grid=grid,
         in_specs=[pl.BlockSpec((ROWS, LANES), lambda i: (i, 0)),
                   pl.BlockSpec((ROWS, 1), lambda i: (i, 0))],

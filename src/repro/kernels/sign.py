@@ -69,6 +69,7 @@ def ef_sign_fused(g, e, *, gamma: float, interpret: bool = False):
     sspec = pl.BlockSpec((ROWS, 1), lambda i: (i, 0))
     sign, s, r = pl.pallas_call(
         functools.partial(_kernel, gamma=gamma),
+        name="ef_sign_fused",
         grid=grid,
         in_specs=[spec, spec],
         out_specs=[spec, sspec, spec],
@@ -96,4 +97,5 @@ def ef_sign_gather(fb, eb, perm, *, gamma: float,
         return sign, scale, ef - sign * scale
 
     out_defs = [(LANES, jnp.int8), (1, jnp.float32), (LANES, jnp.float32)]
-    return gather_ef_call(body, fb, eb, perm, out_defs, interpret=interpret)
+    return gather_ef_call(body, fb, eb, perm, out_defs,
+                          name="ef_sign_gather", interpret=interpret)

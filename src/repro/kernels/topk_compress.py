@@ -48,7 +48,7 @@ BISECT_ITERS = 16
 MAX_GATHER_ROWS = 131072
 
 
-def gather_ef_call(body, fb, eb, perm, out_defs, *,
+def gather_ef_call(body, fb, eb, perm, out_defs, *, name: str,
                    interpret: bool = False):
     """Run a per-row encode ``body`` directly on gathered bucket rows.
 
@@ -56,7 +56,8 @@ def gather_ef_call(body, fb, eb, perm, out_defs, *,
     buffers (zero row last); ``perm``: (S,) int32 block indices.
     ``body(g, e) -> tuple`` maps (1, LANES) f32 row tiles to the per-row
     encode outputs; ``out_defs`` lists each output's ``(width, dtype)``
-    (outputs are (S, width)).
+    (outputs are (S, width)).  ``name`` names the kernel: its caller's
+    name, which the trace shows for the call.
 
     The gather never materialises in HBM: the perm rides in
     scalar-prefetch memory and the input index map reads block
@@ -94,7 +95,7 @@ def gather_ef_call(body, fb, eb, perm, out_defs, *,
             + [pl.BlockSpec(memory_space=pl.ANY)] * len(outs),
             out_specs=out_specs)
         return pl.pallas_call(
-            kernel, grid_spec=grid_spec, out_shape=out_shape,
+            kernel, name=name, grid_spec=grid_spec, out_shape=out_shape,
             input_output_aliases={3 + j: j for j in range(len(outs))},
             interpret=interpret,
         )(p_chunk, fb3, eb3, *outs)
@@ -149,6 +150,7 @@ def ef_topk_select(g, e, *, gamma: float, k: int, interpret: bool = False):
     spec = pl.BlockSpec((ROWS, LANES), lambda i: (i, 0))
     out = pl.pallas_call(
         functools.partial(_kernel, gamma=gamma, k=k),
+        name="ef_topk_select",
         grid=grid,
         in_specs=[spec, spec],
         out_specs=[spec, spec],
@@ -173,4 +175,5 @@ def ef_topk_gather(fb, eb, perm, *, gamma: float, k: int,
         return sel, ef - sel
 
     out_defs = [(LANES, jnp.float32), (LANES, jnp.float32)]
-    return gather_ef_call(body, fb, eb, perm, out_defs, interpret=interpret)
+    return gather_ef_call(body, fb, eb, perm, out_defs,
+                          name="ef_topk_gather", interpret=interpret)

@@ -56,6 +56,7 @@ from __future__ import annotations
 import argparse
 import inspect
 import json
+import os
 import threading
 import time
 from typing import Dict, List, Optional, Union
@@ -63,6 +64,7 @@ from typing import Dict, List, Optional, Union
 import jax
 import numpy as np
 
+from repro import obs
 from repro.configs.base import RunConfig
 from repro.checkpoint.checkpointer import Checkpointer
 from repro.core import acesync
@@ -72,8 +74,8 @@ from repro.hierarchy import ClusterState
 from repro.runtime import faults as F
 from repro.runtime.fault_tolerance import (ElasticPlanner, HeartbeatMonitor,
                                            MeshPlan, StragglerDetector)
-from repro.strategies import (STEP_ADVANCING, SYNC_KINDS, SyncStrategy,
-                              list_strategies)
+from repro.strategies import (STEP_ADVANCING, STEP_KINDS, SYNC_KINDS,
+                              SyncStrategy, list_strategies)
 
 
 def _device_ready(x) -> bool:
@@ -136,6 +138,10 @@ class TrainLoop:
         #: runs of the same config replay the same plan/H/membership
         #: trajectory step for step (the restart-replay soak pins this)
         self.blocking_replans = bool(blocking_replans)
+        #: one record per host step: ``step``, ``H``, the step's metrics
+        #: and ``dt``, the host loop time of the step on ``perf_counter``
+        #: (its dispatch and the lagged metric flush of the step before).
+        #: The heartbeat reads it; it is not the device's step time.
         self.history = []
         self.comm_bytes = 0.0
         self._plan = None
@@ -536,7 +542,7 @@ class TrainLoop:
         if log_every and idx % log_every == 0:
             print(f"step {rec['step']:5d} "
                   f"loss={rec.get('loss', float('nan')):.4f} "
-                  f"H={rec['H']} dt={rec['dt']:.2f}s", flush=True)
+                  f"H={rec['H']} host_dt={rec['dt']:.2f}s", flush=True)
 
     def run_steps(self, state, pipeline, n_steps: int,
                   log_every: int = 10):
@@ -556,26 +562,32 @@ class TrainLoop:
         for i in range(n_steps):
             step = self._host_step
             self._apply_faults(step)
-            state = self._poll_elastic(state,
-                                       block=self.blocking_replans)
-            self.poll_replan()
+            with obs.span("loop.elastic"):
+                state = self._poll_elastic(state,
+                                           block=self.blocking_replans)
+            with obs.span("loop.poll"):
+                self.poll_replan()
             if step and step % cfg.replan_every == 0:
-                self.refresh_plan(state, step)
-                if self.blocking_replans:
-                    self.poll_replan(block=True)
-                H = self.adapt_interval(state)
+                with obs.span("loop.replan"):
+                    self.refresh_plan(state, step)
+                    if self.blocking_replans:
+                        self.poll_replan(block=True)
+                    H = self.adapt_interval(state)
                 self._H = H
-            batch = next(self._pipeline)
-            t0 = time.time()
+            with obs.span("loop.data"):
+                batch = next(self._pipeline)
+            t0 = time.perf_counter()
             kinds = self.strategy.step_schedule(self._steps_since_sync, H)
             metrics = {}
-            for kind in kinds:
-                state, m = self.trainer.step(state, batch, self._plan, kind)
-                metrics.update(m)
-                self.comm_bytes += self.strategy.wire_bytes(
-                    self.trainer.scheduler, self._plan, kind)
-                if kind in STEP_ADVANCING:
-                    self._host_step += 1
+            with obs.span("loop.dispatch"):
+                for kind in kinds:
+                    state, m = self.trainer.step(state, batch, self._plan,
+                                                 kind)
+                    metrics.update(m)
+                    self.comm_bytes += self.strategy.wire_bytes(
+                        self.trainer.scheduler, self._plan, kind)
+                    if kind in STEP_ADVANCING:
+                        self._host_step += 1
             if SYNC_KINDS & set(kinds):
                 self._steps_since_sync = 0
             else:
@@ -585,19 +597,25 @@ class TrainLoop:
             # flight
             jax.tree.map(_to_host_async, metrics)
             if inflight is not None:
-                self._flush_metrics(inflight, log_every)
-            dt = time.time() - t0
-            for pod in self._beat_pods():
-                self.monitor.beat(pod, dt)
-            newly_dead = self.monitor.check()
-            if newly_dead:
-                self._on_pods_dead(newly_dead)
+                with obs.span("loop.flush"):
+                    self._flush_metrics(inflight, log_every)
+            # host loop time of the step (dispatch and the lagged flush of
+            # the step before), not its device time: the heartbeat's input
+            dt = time.perf_counter() - t0
+            with obs.span("loop.health"):
+                for pod in self._beat_pods():
+                    self.monitor.beat(pod, dt)
+                newly_dead = self.monitor.check()
+                if newly_dead:
+                    self._on_pods_dead(newly_dead)
             inflight = (metrics, dict(step=step, dt=dt, H=H), i)
             done = self._host_step  # state now holds the post-step counter
             if run.ckpt_every and done % run.ckpt_every == 0:
-                self.ckpt.save(done, state, extras=self.ckpt_extras())
+                with obs.span("loop.ckpt"):
+                    self.ckpt.save(done, state, extras=self.ckpt_extras())
         if inflight is not None:
-            self._flush_metrics(inflight, log_every)
+            with obs.span("loop.flush"):
+                self._flush_metrics(inflight, log_every)
         return state
 
     def restore_or_init(self, rng, pipeline):
@@ -615,6 +633,49 @@ class TrainLoop:
         if self.mesh is not None:
             state = jax.device_put(state, self.trainer.state_shardings())
         return state
+
+
+def trace_window(spec: Optional[str], steps: int):
+    """``"A:B"`` -> (A, B) clamped to [0, steps]; None -> the whole run."""
+    if not spec:
+        return 0, steps
+    lo, _, hi = spec.partition(":")
+    a = max(0, min(int(lo) if lo else 0, steps))
+    b = max(0, min(int(hi) if hi else steps, steps))
+    if a >= b:
+        raise ValueError(f"--trace-steps {spec!r}: no step in {a}:{b}")
+    return a, b
+
+
+def run_traced(sess, steps: int, trace_dir: str,
+               window: Optional[str] = None) -> None:
+    """Run ``steps`` host steps with the program's spans on and a
+    profiler trace over host steps ``window``; write ``spans.json``
+    (``obs.export()``) and, for each step kind run, the step's optimised
+    HLO (``step_hlo.<kind>.txt``: instruction names to the ``op_name``
+    scopes the trace's op events lack)."""
+    a, b = trace_window(window, steps)
+    os.makedirs(trace_dir, exist_ok=True)
+    obs.enable()
+    if a:
+        sess.run(a)
+    jax.profiler.start_trace(trace_dir)
+    try:
+        sess.run(b - a)
+        jax.block_until_ready(sess.state)
+    finally:
+        jax.profiler.stop_trace()
+    if steps > b:
+        sess.run(steps - b)
+    with open(os.path.join(trace_dir, "spans.json"), "w") as f:
+        json.dump(obs.export(), f)
+    for kind in STEP_KINDS:
+        try:
+            text = sess.trainer.step_hlo_text(sess.loop.plan, kind)
+        except ValueError:
+            continue        # a kind this run never stepped
+        with open(os.path.join(trace_dir, f"step_hlo.{kind}.txt"), "w") as f:
+            f.write(text)
 
 
 def main():
@@ -637,6 +698,13 @@ def main():
     ap.add_argument("--ckpt-dir", default="/tmp/repro_ckpt")
     ap.add_argument("--ckpt-every", type=int, default=None,
                     help="checkpoint cadence in steps (default: RunConfig)")
+    ap.add_argument("--trace-dir", default=None,
+                    help="record the program's spans and a profiler trace "
+                         "into this directory (spans.json, step_hlo.<kind>"
+                         ".txt, the trace under plugins/profile/)")
+    ap.add_argument("--trace-steps", default=None, metavar="A:B",
+                    help="host steps the profiler records, A inclusive to "
+                         "B exclusive (default: every step)")
     args = ap.parse_args()
 
     run_kw = {}
@@ -646,7 +714,10 @@ def main():
         args.arch, strategy=args.strategy, smoke=args.smoke,
         seq_len=args.seq_len, batch=args.batch, steps=args.steps,
         n_layers=args.layers, warmup_steps=10, ckpt_dir=args.ckpt_dir, **run_kw)
-    sess.run(args.steps)
+    if args.trace_dir is None:
+        sess.run(args.steps)
+    else:
+        run_traced(sess, args.steps, args.trace_dir, args.trace_steps)
     sess.finish()
     losses = sess.losses
     print(json.dumps({"first_loss": losses[0], "last_loss": losses[-1],
