@@ -1,8 +1,8 @@
 """Recompile-hazard audit: the plan must not widen the step cache.
 
 The zero-retrace contract (pinned by tests/test_replan.py) hangs on the
-:class:`~repro.core.planexec.ExecPlan` split: ``perms``/``omega`` are
-pytree CHILDREN (device data — replans swap them without retracing) and
+:class:`~repro.core.planexec.ExecPlan` split: ``perms``/``omega``/``inv``
+are pytree CHILDREN (device data — replans swap them without retracing) and
 everything else is static aux hashed into ``static_key()``.  Three
 drift modes silently break it:
 
@@ -32,7 +32,7 @@ from repro.analysis.report import AuditReport
 
 PASS = "recompile_hazard"
 
-_CHILD_FIELDS = ("perms", "omega")
+_CHILD_FIELDS = ("perms", "omega", "inv")
 
 
 def _is_device_leaf(x) -> bool:

@@ -9,9 +9,11 @@ fresh XLA compile — up to L^G variants for G groups over an L-rung ladder.
   * every parameter group is laid out block-aligned in one static flat
     (NB, block) buffer (``leaf_layout``), computed once per (model, mesh);
   * per rung, a gather permutation ``perm_r: int32[S_r]`` of block indices
-    repacks the member groups into one contiguous per-rung buffer.  The
-    perms are ordinary device arrays — replans swap them without
-    retracing;
+    repacks the member groups into one contiguous per-rung buffer, and
+    one inverse permutation ``inv: int32[NB]`` maps every layout row back
+    to the row of *bucket space* (the rung buffers end to end) that holds
+    it, so the exchange's outputs return to the leaves by gather.  Perms and inverse are ordinary device arrays —
+    replans swap them without retracing;
   * only the tuple of padded per-rung block counts — the **bucket-shape
     signature** — plus the per-rung **chunk grid** of the ring exchange is
     static.  Rung sizes are rounded up to a small geometric ladder of size
@@ -534,14 +536,19 @@ def seg_grids(level_idx: Sequence[int], layout: LeafLayout,
 class ExecPlan:
     """A SyncPlan lowered to device data + a static bucket signature.
 
-    Registered as a pytree: ``perms`` and ``omega`` are children (traced,
-    swapped per replan), everything else is aux data (hashed into the jit
-    cache key).  ``total_blocks`` is the NB of the *local* leaf layout the
-    perms index into (one zero pad block lives at index NB).  ``chunks``
-    is the static per-rung chunk grid of the ring exchange (0 = one-shot;
-    see :func:`ring_chunk_count`); ``bidir`` selects the bidirectional
-    half-ring circulation for ringing rungs (static: it changes the
-    lowered ppermute pattern).
+    Registered as a pytree: ``perms``, ``omega`` and ``inv`` are children
+    (traced, swapped per replan), everything else is aux data (hashed into
+    the jit cache key).  ``total_blocks`` is the NB of the *local* leaf
+    layout the perms index into (one zero pad block lives at index NB).
+    ``inv`` holds one ``int32[NB]`` per leaf range (one when unsegmented,
+    one per segment otherwise): ``inv[i]`` is the bucket-space row of
+    layout row ``i`` — bucket space being the range's rung buckets laid
+    end to end in rung order, each its padded ``S_r`` rows.  Its shape
+    depends only on the layout, never on the plan.
+    ``chunks`` is the static per-rung chunk grid of the ring exchange
+    (0 = one-shot; see :func:`ring_chunk_count`); ``bidir`` selects the
+    bidirectional half-ring circulation for ringing rungs (static: it
+    changes the lowered ppermute pattern).
 
     Backward-segmented plans (``build_exec_plan(segments > 1)``)
     additionally carry the static segment schedule: ``seg_leaves`` are
@@ -561,6 +568,7 @@ class ExecPlan:
     total_blocks: int
     perms: tuple                      # int32[S_r] per rung with sig[r] > 0
     omega: jax.Array                  # f32[n_fleet] aggregation weights
+    inv: tuple = ()                   # int32[NB] per leaf range
     chunks: Tuple[int, ...] = ()      # ring chunk count per rung
     bidir: bool = True                # both DCN directions at once
     hier: Tuple[int, ...] = ()        # per-rung tier grid (0/1/2)
@@ -586,7 +594,7 @@ class ExecPlan:
 
 jax.tree_util.register_pytree_node(
     ExecPlan,
-    lambda ep: ((ep.perms, ep.omega),
+    lambda ep: ((ep.perms, ep.omega, ep.inv),
                 (ep.levels, ep.sig, ep.block, ep.total_blocks, ep.chunks,
                  ep.bidir, ep.hier, ep.seg_leaves, ep.seg_nb, ep.seg_sig,
                  ep.seg_chunks, ep.seg_hier)),
@@ -595,34 +603,49 @@ jax.tree_util.register_pytree_node(
                              bidir=aux[5], hier=aux[6], seg_leaves=aux[7],
                              seg_nb=aux[8], seg_sig=aux[9],
                              seg_chunks=aux[10], seg_hier=aux[11],
-                             perms=tuple(ch[0]), omega=ch[1]),
+                             perms=tuple(ch[0]), omega=ch[1],
+                             inv=tuple(ch[2])),
 )
 
 
-def _rung_perms(level_idx, nbs, starts, sig, base: int, pad: int,
-                lo: int, hi: int, L: int) -> Tuple[jax.Array, ...]:
-    """Gather perms for leaves [lo, hi): one int32[sig[r]] per rung with a
-    non-empty bucket, indices relative to ``base`` (the range's first
-    block), pad entries pointing at the zero row ``pad``."""
+def _rung_perms(level_idx, nbs, starts, sig, base: int, nb: int,
+                lo: int, hi: int, L: int):
+    """Gather perms and their inverse for leaves [lo, hi) spanning ``nb``
+    layout rows from block ``base``: one int32[sig[r]] per rung with a
+    non-empty bucket, indices relative to ``base``, pad entries pointing
+    at the zero row ``nb``; and ``inv: int32[nb]``, each layout row's row
+    of bucket space (the rung buckets end to end).
+
+    Raises unless every layout row lands in exactly one rung bucket: the
+    return path gathers each leaf through ``inv`` and has no other source
+    for a row no rung wrote, so bucket space needs no zero row."""
     member = [[] for _ in range(L)]
     for i in range(lo, hi):
         if nbs[i]:
             member[level_idx[i]].append(
                 np.arange(starts[i] - base, starts[i] - base + nbs[i],
                           dtype=np.int32))
-    perms = []
+    perms, rows = [], []
+    inv = np.zeros((nb,), np.int32)
+    off = 0
     for r in range(L):
         S = sig[r]
         if not S:
             continue
         idx = (np.concatenate(member[r]) if member[r]
                else np.zeros((0,), np.int32))
-        # pad entries gather the zero block at index ``pad`` and scatter
-        # back into it — they never touch real data
-        p = np.full((S,), pad, np.int32)
+        rows.append(idx)
+        inv[idx] = off + np.arange(idx.shape[0])
+        # pad entries gather the zero block at index ``nb``
+        p = np.full((S,), nb, np.int32)
         p[: idx.shape[0]] = idx
         perms.append(jnp.asarray(p))
-    return tuple(perms)
+        off += S
+    rows = np.concatenate(rows) if rows else np.zeros((0,), np.int32)
+    if not np.array_equal(np.sort(rows), np.arange(nb)):
+        raise ValueError("every layout row must lie in exactly one rung "
+                         "bucket")
+    return tuple(perms), jnp.asarray(inv)
 
 
 def build_exec_plan(plan, sizes: Optional[Sequence[int]] = None, *,
@@ -641,9 +664,9 @@ def build_exec_plan(plan, sizes: Optional[Sequence[int]] = None, *,
     per-replan recomputation.  ``growth``: padded-class ladder for
     adaptive plans (``None`` = exact sizes, right for plans that never
     change).  ``n_pods``/``ring`` feed the chunk-grid heuristic (a 1-pod
-    build never rings).  The perms are numpy-built (O(total_blocks),
-    trivial next to a train step) and uploaded once per distinct
-    assignment.
+    build never rings).  The perms and their inverse are numpy-built
+    (O(total_blocks), trivial next to a train step) and uploaded once per
+    distinct assignment.
 
     ``segments > 1`` builds the backward-interleaved plan: leaves split
     into contiguous ranges (:func:`segment_leaf_bounds`), each range
@@ -680,18 +703,17 @@ def build_exec_plan(plan, sizes: Optional[Sequence[int]] = None, *,
         level_idx, layout, plan.levels, n_pods, growth, ring, bidir,
         n_edge=n_edge, hier=hier, segments=segments)
     if len(bounds) > 2:
-        seg_perms = []
-        for s in range(len(bounds) - 1):
-            lo, hi = bounds[s], bounds[s + 1]
-            base = starts[lo]
-            seg_perms.append(_rung_perms(level_idx, nbs, starts,
-                                         seg_sig[s], base, seg_nb[s],
-                                         lo, hi, L))
-        return ExecPlan(perms=tuple(seg_perms), seg_leaves=bounds,
+        seg_perms, seg_inv = zip(*(
+            _rung_perms(level_idx, nbs, starts, seg_sig[s],
+                        starts[bounds[s]], seg_nb[s], bounds[s],
+                        bounds[s + 1], L)
+            for s in range(len(bounds) - 1)))
+        return ExecPlan(perms=seg_perms, inv=seg_inv, seg_leaves=bounds,
                         seg_nb=seg_nb, seg_sig=seg_sig,
                         seg_chunks=seg_chunks, seg_hier=seg_hier, **kw)
-    return ExecPlan(perms=_rung_perms(level_idx, nbs, starts, sig, 0, NB,
-                                      0, len(level_idx), L), **kw)
+    perms, inv = _rung_perms(level_idx, nbs, starts, sig, 0, NB, 0,
+                             len(level_idx), L)
+    return ExecPlan(perms=perms, inv=(inv,), **kw)
 
 
 def exec_wire_bytes(ep: ExecPlan, n_pods: int,

@@ -21,13 +21,14 @@ exchange round on that buffer — ONE pod collective (all_gather/psum) for
 small buckets, or the plan's K-chunk ``ppermute`` ring for DCN-bound ones
 (``Codec.ef_sync_ring``: the transfer of chunk *i* hides the
 decode-accumulate of chunk *i-1*; exactly the same bytes on the wire) —
-and the aggregate/residual are scattered back through the same
-permutation.  Only the tuple of padded per-rung block counts — the
-bucket-shape signature — plus the per-rung chunk grid is static, so a
-replan that keeps the signature swaps permutations without recompiling
-(tests/test_replan.py pins this; tests/test_collectives.py keeps pinning
-the collectives-per-rung and analytic==traced byte contracts, now with
-the per-leaf block padding priced explicitly).
+and the aggregate/residual return to the leaves by one row gather per
+leaf through the plan's inverse permutation (``ExecPlan.inv``) out of the
+rung outputs laid end to end.  Only the tuple of padded per-rung block
+counts — the bucket-shape signature — plus the per-rung chunk grid is
+static, so a replan that keeps the signature swaps permutations without
+recompiling (tests/test_replan.py pins this; tests/test_collectives.py
+keeps pinning the collectives-per-rung and analytic==traced byte
+contracts, now with the per-leaf block padding priced explicitly).
 
 The trainer-level counterpart is rung-ordered apply (``apply_fn``): the
 optimizer consumes each rung's aggregate the moment it lands, so the
@@ -246,11 +247,32 @@ def _rung_exchange(codec, fb, eb, perm, omega, omega_own, *, chunks,
         fixed_bits=fixed_bits)
 
 
-def _range_sync(gs, es, aux, perms, sig, chunks, hgrid, NB, *, levels,
+def _bucket_space(rows, block: int) -> jax.Array:
+    """The rung outputs ``(S_r, block)`` laid end to end in rung order:
+    the rows ``ExecPlan.inv`` indexes.  A single rung's output is its own
+    bucket space, with no copy."""
+    return (jnp.concatenate(rows) if rows
+            else jnp.zeros((0, block), jnp.float32))
+
+
+def _unpack(src, inv, like, block: int) -> tuple:
+    """Leaves shaped and typed like ``like``, each gathered straight out of
+    bucket space ``src`` through its rows of the inverse permutation."""
+    outs, boff = [], 0
+    for leaf in like:
+        n = math.prod(leaf.shape)
+        nb = n_blocks(n, block)
+        flat = src[inv[boff:boff + nb]].reshape(-1)
+        outs.append(flat[:n].reshape(leaf.shape).astype(leaf.dtype))
+        boff += nb
+    return tuple(outs)
+
+
+def _range_sync(gs, es, aux, perms, inv, sig, chunks, hgrid, *, levels,
                 block, omega, omega_own, omega_intra, scalars, bidir,
                 gamma, n_pods, n_cross, n_edge, use_pallas, fixed_bits,
                 apply_fn):
-    """One leaf range's pack + per-rung exchange + scatter + unpack.
+    """One leaf range's pack + per-rung exchange + return to the leaves.
 
     The whole tree is one range on the barriered path; the backward-
     streaming path calls this once per segment — crucially the packed
@@ -260,22 +282,28 @@ def _range_sync(gs, es, aux, perms, sig, chunks, hgrid, NB, *, levels,
     backward still runs.  Returns ``(aggs | aux_outs, errs)`` as leaf
     tuples for the range.
 
+    The way back is a gather, never a scatter: the rung outputs are laid
+    end to end (bucket space) and each output leaf is gathered from them
+    through ``inv``, the plan's inverse of the rung perms, which maps
+    every layout row to exactly one bucket row (``build_exec_plan``
+    checks that).
+
     Named scopes (inside the caller's ``exchange``): ``pack``, one
     ``encode_<RUNG>`` per rung, ``collective``, ``decode``, ``scatter``
-    and ``unpack``."""
+    (the aux row gathers feeding ``apply_fn`` and the bucket-space
+    assembly) and ``unpack`` (the inverse gathers that write the
+    leaves)."""
     with jax.named_scope("pack"):
         fb = _leaf_blocks(gs, block)
         eb = _leaf_blocks(es, block)
-        assert fb.shape[0] == NB, \
-            f"leaf layout has {fb.shape[0]} blocks, plan was built for {NB}"
+        assert fb.shape[0] == inv.shape[0], \
+            (f"leaf layout has {fb.shape[0]} blocks, plan was built for "
+             f"{inv.shape[0]}")
         zrow = jnp.zeros((1, block), jnp.float32)
         fb = jnp.concatenate([fb, zrow])
         eb = jnp.concatenate([eb, zrow])
         abufs = [jnp.concatenate([_leaf_blocks(a, block), zrow])
                  for a in aux]
-        agg = None if apply_fn is not None \
-            else jnp.zeros((NB + 1, block), jnp.float32)
-        err = jnp.zeros((NB + 1, block), jnp.float32)
     # Encode pass: every payload-gather rung (one-shot multi-pod path)
     # stops at its packed uint8 wire buffer; the wires are concatenated
     # into ONE all_gather per range instead of one per rung — same bytes,
@@ -323,7 +351,9 @@ def _range_sync(gs, es, aux, perms, sig, chunks, hgrid, NB, *, levels,
             coal = wire_parts[0] if len(wire_parts) == 1 \
                 else jnp.concatenate(wire_parts)
             gathered = jax.lax.all_gather(coal, axis)
-    # Decode + scatter pass, in rung order (the perms are disjoint).
+    # Decode pass, in rung order: each rung's outputs as (S, block) rows
+    # (rung-ordered apply consumes each aggregate as it lands).
+    errs_rows, outs_rows = [], []
     for S, perm, codec, payload in staged:
         if codec is None:
             b_agg, b_err = payload
@@ -334,49 +364,47 @@ def _range_sync(gs, es, aux, perms, sig, chunks, hgrid, NB, *, levels,
                     gathered[:, o:o + nbytes], meta, omega, n=S * block,
                     block=block, use_pallas=use_pallas,
                     deterministic=n_pods >= 3, fixed_bits=fixed_bits)
-        # apply_fn names its own scope (the trainer's ``optimizer``)
-        with jax.named_scope("scatter"):
-            err = err.at[perm].set(b_err.reshape(S, block))
-            if apply_fn is None:
-                agg = agg.at[perm].set(b_agg.reshape(S, block))
-            else:
-                rows = apply_fn(b_agg.reshape(S, block),
-                                tuple(ab[perm] for ab in abufs), scalars)
-                abufs = [ab.at[perm].set(nr)
-                         for ab, nr in zip(abufs, rows)]
-
-    def unpack(flat_buf, like):
-        outs, boff = [], 0
-        for leaf in like:
-            n = math.prod(leaf.shape)
-            o = boff * block
-            outs.append(flat_buf[o:o + n].reshape(leaf.shape)
-                        .astype(leaf.dtype))
-            boff += n_blocks(n, block)
-        return tuple(outs)
-
-    with jax.named_scope("unpack"):
-        errs = unpack(err[:NB].reshape(-1), es)
+        errs_rows.append(b_err.reshape(S, block))
         if apply_fn is None:
-            return unpack(agg[:NB].reshape(-1), gs), errs
-        outs = tuple(unpack(ab[:NB].reshape(-1), a)
-                     for ab, a in zip(abufs, aux))
+            outs_rows.append(b_agg.reshape(S, block))
+        else:
+            with jax.named_scope("scatter"):
+                aux_rows = tuple(ab[perm] for ab in abufs)
+            # apply_fn names its own scope (the trainer's ``optimizer``)
+            outs_rows.append(apply_fn(b_agg.reshape(S, block), aux_rows,
+                                      scalars))
+
+    with jax.named_scope("scatter"):
+        err_src = _bucket_space(errs_rows, block)
+        if apply_fn is None:
+            agg_src = _bucket_space(outs_rows, block)
+        else:
+            aux_srcs = [_bucket_space([rows[a] for rows in outs_rows],
+                                      block) for a in range(len(aux))]
+    with jax.named_scope("unpack"):
+        errs = _unpack(err_src, inv, es, block)
+        if apply_fn is None:
+            return _unpack(agg_src, inv, gs, block), errs
+        outs = tuple(_unpack(src, inv, a, block)
+                     for src, a in zip(aux_srcs, aux))
     return outs, errs
 
 
-def _repack_sync_local(gs, es, perms, omega, omega_own, omega_intra, aux,
-                       scalars, *, ep: ExecPlan, gamma, n_pods, n_cross,
-                       n_edge, use_pallas, fixed_bits, apply_fn=None):
+def _repack_sync_local(gs, es, perms, inv, omega, omega_own, omega_intra,
+                       aux, scalars, *, ep: ExecPlan, gamma, n_pods,
+                       n_cross, n_edge, use_pallas, fixed_bits,
+                       apply_fn=None):
     """Fully local per-device sync of the whole tree through the plan's
-    gather/scatter repacking.
+    gather repacking.
 
     ``gs`` / ``es``: tuples of local shard arrays (grads and EF residuals)
     in leaf order.  They are packed into the static block layout, each
     rung's bucket is gathered through its permutation (device data — the
-    only thing a replan changes), pushed through the codec's fused EF +
-    compress + exchange round (ring-chunked where the plan says so),
-    and scattered back.  Pad blocks gather the zero row at index NB and
-    scatter into it, so they never touch real data.
+    only thing a replan changes, with its inverse ``inv``), pushed through
+    the codec's fused EF + compress + exchange round (ring-chunked where
+    the plan says so), and each output leaf is gathered back out of the
+    rung outputs through ``inv``.  Pad blocks gather the zero row at
+    index NB, and no layout row reads a pad row back.
 
     Rung-ordered apply: with ``apply_fn`` set, ``aux`` is a tuple of
     leaf-tuples (e.g. params / m / v) packed into the same block layout,
@@ -405,8 +433,8 @@ def _repack_sync_local(gs, es, perms, omega, omega_own, omega_intra, aux,
               use_pallas=use_pallas, fixed_bits=fixed_bits,
               apply_fn=apply_fn)
     if not ep.segmented:
-        return _range_sync(gs, es, aux, perms, ep.sig, ep.chunks,
-                           ep.hier, ep.total_blocks, **kw)
+        return _range_sync(gs, es, aux, perms, inv[0], ep.sig, ep.chunks,
+                           ep.hier, **kw)
     S = len(ep.seg_sig)
     outs: list = [None] * S
     errs: list = [None] * S
@@ -414,8 +442,8 @@ def _repack_sync_local(gs, es, perms, omega, omega_own, omega_intra, aux,
         lo, hi = ep.seg_leaves[s], ep.seg_leaves[s + 1]
         outs[s], errs[s] = _range_sync(
             gs[lo:hi], es[lo:hi], tuple(a[lo:hi] for a in aux),
-            perms[s], ep.seg_sig[s], ep.seg_chunks[s], ep.seg_hier[s],
-            ep.seg_nb[s], **kw)
+            perms[s], inv[s], ep.seg_sig[s], ep.seg_chunks[s],
+            ep.seg_hier[s], **kw)
     err_leaves = tuple(e for seg in errs for e in seg)
     if apply_fn is None:
         return tuple(g for seg in outs for g in seg), err_leaves
@@ -543,26 +571,28 @@ def sync_tree(tree, errors, plan: Union[SyncPlan, ExecPlan], *, mesh,
                               for ax in aspec]))
         aspecs = tuple(aspecs)
         # mirror the perm structure (flat per-rung, or nested per-segment
-        # for backward-streaming plans): every perm rides replicated
+        # for backward-streaming plans): every perm and inverse rides
+        # replicated
         pspecs = jax.tree.map(lambda _: P(None), ep.perms)
+        ispecs = jax.tree.map(lambda _: P(None), ep.inv)
         aux_specs = tuple(aspecs for _ in aux)
         scalar_specs = tuple(P() for _ in scalars)
         out_main = (tuple(aspecs for _ in aux) if apply_fn is not None
                     else aspecs)
         inner = jax.shard_map(
             fn, mesh=mesh,
-            in_specs=(aspecs, aspecs, pspecs, P(None), P(), P(None),
-                      aux_specs, scalar_specs),
+            in_specs=(aspecs, aspecs, pspecs, ispecs, P(None), P(),
+                      P(None), aux_specs, scalar_specs),
             out_specs=(out_main, aspecs),
             axis_names=set(_auto_axes(mesh)), check_vma=False)
-        aggs, news = inner(gs, es, ep.perms, omega, omega_own,
+        aggs, news = inner(gs, es, ep.perms, ep.inv, omega, omega_own,
                            omega_intra, aux, scalars)
     else:
         # no mesh, or the fully-manual per-pod region (leaves whole and
         # replicated over data/model there): device-local math, pod
         # collectives bound by the enclosing manual region
-        aggs, news = fn(gs, es, ep.perms, omega, omega_own, omega_intra,
-                        aux, scalars)
+        aggs, news = fn(gs, es, ep.perms, ep.inv, omega, omega_own,
+                        omega_intra, aux, scalars)
     news_tree = jax.tree_util.tree_unflatten(treedef, list(news))
     if apply_fn is not None:
         out_trees = tuple(jax.tree_util.tree_unflatten(treedef, list(a))

@@ -13,6 +13,7 @@ Pins the three contracts of the planexec refactor:
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from repro.configs import SMOKE_ARCHS
 from repro.configs.base import ACESyncConfig, RunConfig, ShapeConfig
@@ -99,6 +100,12 @@ class TestRetraceFree:
             assert np.isfinite(float(m["loss"]))
         assert tr.compile_count() == warm, \
             f"replanning retraced: {warm} -> {tr.compile_count()}"
+        # the inverse permutations differ between the swaps: they ride as
+        # data, like the perms
+        invs = {b"".join(np.asarray(x).tobytes()
+                         for x in tr.exec_plan(p).inv)
+                for p in (plan, *variants)}
+        assert len(invs) == len(variants) + 1
 
     def test_omega_is_data_too(self):
         """Changing aggregation weights never recompiles either."""
@@ -274,8 +281,8 @@ class TestPlanVectorParity:
         sync_tree's apply_fn path, the new default) must match the
         whole-tree _optimize barrier path: same grads, same plan, same
         state -> same params / moments / EF residuals.  Guards the
-        pack/gather/scatter invariants (intra-block tail padding and the
-        shared zero row at index NB stay inert across rungs)."""
+        pack/gather invariants (intra-block tail padding and the shared
+        zero row at index NB stay inert across rungs)."""
         cfg = SMOKE_ARCHS["paper-350m"]
 
         def run(overlap):
@@ -389,6 +396,62 @@ class TestBucketSignature:
         assert perm.shape == (4,)
         assert list(perm[:3]) == [0, 1, 2]
         assert perm[3] == ep.total_blocks        # pad -> the zero block
+
+
+class TestInversePermutation:
+    """``ExecPlan.inv`` maps every layout row to the row of bucket space
+    (the rung buckets end to end) that holds it."""
+
+    LEVELS = tuple(Level(*l) for l in ACESyncConfig().levels)
+    SIZES = [1000, 2048, 231, 4096, 3000, 300, 5000]
+    RUNGS = ("FULL", "INT8", "SKIP", "INT8", "FULL", "INT4", "INT8")
+
+    def _plan(self, rungs):
+        names = [l.name for l in self.LEVELS]
+        return SyncPlan(tuple(names.index(n) for n in rungs), self.LEVELS,
+                        (0.5, 0.5), 1)
+
+    @pytest.mark.parametrize("kw", [
+        dict(), dict(growth=2.0), dict(growth=1.125, segments=2),
+        dict(n_pods=2, ring=2), dict(growth=2.0, segments=3)],
+        ids=["exact", "class-padded", "2-segment", "ring", "3-segment"])
+    def test_inv_inverts_the_perms(self, kw):
+        ep = build_exec_plan(self._plan(self.RUNGS), self.SIZES, **kw)
+        if ep.segmented:
+            ranges = zip(ep.seg_nb, ep.perms, ep.seg_sig, ep.inv)
+        else:
+            ranges = [(ep.total_blocks, ep.perms, ep.sig, ep.inv[0])]
+        for nb, perms, sig, inv in ranges:
+            inv = np.asarray(inv)
+            assert inv.shape == (nb,) and inv.dtype == np.int32
+            seen, off = [], 0
+            for perm, S in zip(perms, [S for S in sig if S]):
+                perm = np.asarray(perm)
+                real = perm[perm != nb]
+                np.testing.assert_array_equal(
+                    inv[real], off + np.arange(real.shape[0]))
+                seen.append(real)
+                off += S
+            # every real row in exactly one bucket, never in a pad row
+            np.testing.assert_array_equal(np.sort(np.concatenate(seen)),
+                                          np.arange(nb))
+            assert inv.max() < off
+
+    def test_inv_shape_depends_only_on_the_layout(self):
+        shapes = set()
+        for rungs in (self.RUNGS, ("INT8",) * 7, ("SKIP", "FULL") * 3
+                      + ("INT4",)):
+            ep = build_exec_plan(self._plan(rungs), self.SIZES,
+                                 growth=1.125, segments=2)
+            shapes.add(tuple(np.asarray(x).shape for x in ep.inv))
+        assert len(shapes) == 1
+
+    def test_a_row_in_no_bucket_is_refused(self):
+        layout = planexec.leaf_layout([2048, 1024])
+        with pytest.raises(ValueError, match="exactly one rung bucket"):
+            # rung 1 holds leaf 1 but its bucket was given no rows
+            planexec._rung_perms((0, 1), layout.nbs, layout.starts,
+                                 (2, 0), 0, layout.total_blocks, 0, 2, 2)
 
 
 class TestChunkGrid:

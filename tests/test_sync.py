@@ -1,13 +1,24 @@
 """Hierarchical sync semantics (single-device path; the multi-pod path is
 covered by tests/test_multipod.py in a subprocess with 8 virtual devices)."""
+import math
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
-from repro.configs.base import ACESyncConfig
+from repro.configs import SMOKE_ARCHS
+from repro.configs.base import ACESyncConfig, RunConfig, ShapeConfig
 from repro.core import sync as S
 from repro.core.compression import Level
+from repro.core.planexec import build_exec_plan, n_blocks
 from repro.core.scheduler import Scheduler, SyncPlan
+from repro.core.trainer import Trainer
+from repro.data.pipeline import TokenPipeline
+from repro.kernels.decode import FIXED_POINT_BITS
+from repro.models.registry import build_model
+from repro.optim import adamw
 
 
 def _tree(seed=0):
@@ -162,6 +173,159 @@ class TestBucketedSync:
         expect = lv["TOPK10_INT8"].wire_bytes(4 * 1024, 2) \
             + lv["INT8"].wire_bytes(2048, 2)
         assert got == expect
+
+
+def _scatter_unpack_sync(tree, errors, ep, *, gamma, apply_fn=None,
+                         aux=(), scalars=()):
+    """The return path the exchange had before the inverse gather, kept as
+    the oracle: per leaf range, each rung's outputs scattered into zeroed
+    (NB+1, block) buffers through the rung's perm, then the leaves sliced
+    back out.  One pod, the jnp codecs."""
+    leaves, treedef = jax.tree_util.tree_flatten(tree)
+    e_leaves = treedef.flatten_up_to(errors)
+    a_leaves = [treedef.flatten_up_to(a) for a in aux]
+    block = ep.block
+    omega = jnp.ones((1,), jnp.float32)
+    if ep.segmented:
+        ranges = [(ep.seg_leaves[s], ep.seg_leaves[s + 1], ep.perms[s],
+                   ep.seg_sig[s]) for s in range(len(ep.seg_sig))]
+    else:
+        ranges = [(0, len(leaves), ep.perms, ep.sig)]
+
+    def unpack(buf, like):
+        flat, outs, boff = buf[:-1].reshape(-1), [], 0
+        for leaf in like:
+            n = math.prod(leaf.shape)
+            outs.append(flat[boff * block:boff * block + n]
+                        .reshape(leaf.shape).astype(leaf.dtype))
+            boff += n_blocks(n, block)
+        return outs
+
+    outs = [[] for _ in range(len(aux) if apply_fn else 1)]
+    errs = []
+    for lo, hi, perms, sig in ranges:
+        zrow = jnp.zeros((1, block), jnp.float32)
+        fb = jnp.concatenate([S._leaf_blocks(leaves[lo:hi], block), zrow])
+        eb = jnp.concatenate([S._leaf_blocks(e_leaves[lo:hi], block),
+                              zrow])
+        abufs = [jnp.concatenate([S._leaf_blocks(a[lo:hi], block), zrow])
+                 for a in a_leaves]
+        agg = jnp.zeros(fb.shape, jnp.float32)
+        err = jnp.zeros(fb.shape, jnp.float32)
+        live = [(r, n) for r, n in enumerate(sig) if n]
+        for (r, n), perm in zip(live, perms):
+            b_agg, b_err = S._rung_exchange(
+                ep.levels[r].codec, fb, eb, perm, omega, omega[0],
+                chunks=0, bidir=True, gamma=gamma, n_pods=1, block=block,
+                use_pallas=False, fixed_bits=FIXED_POINT_BITS)
+            err = err.at[perm].set(b_err.reshape(n, block))
+            if apply_fn is None:
+                agg = agg.at[perm].set(b_agg.reshape(n, block))
+            else:
+                rows = apply_fn(b_agg.reshape(n, block),
+                                tuple(ab[perm] for ab in abufs), scalars)
+                abufs = [ab.at[perm].set(nr) for ab, nr in zip(abufs, rows)]
+        errs += unpack(err, e_leaves[lo:hi])
+        if apply_fn is None:
+            outs[0] += unpack(agg, leaves[lo:hi])
+        else:
+            for o, ab, a in zip(outs, abufs, a_leaves):
+                o += unpack(ab, a[lo:hi])
+    trees = [jax.tree_util.tree_unflatten(treedef, o) for o in outs]
+    return (tuple(trees) if apply_fn else trees[0],
+            jax.tree_util.tree_unflatten(treedef, errs))
+
+
+def _bits(x):
+    return np.asarray(x).view(np.uint32)
+
+
+class TestReturnPath:
+    """The exchange's outputs return to the leaves by one gather per leaf
+    through the plan's inverse permutation, bit for bit what scattering
+    into zeroed buffers and slicing them apart gave."""
+
+    SIZES = {"a": (1000,), "b": (64, 32), "c": (3, 7, 11), "d": (4096,),
+             "e": (3000,), "f": (300,), "g": (0, 3)}
+    RUNGS = ("FULL", "INT8", "SKIP", "INT8", "FULL", "INT8", "FULL")
+
+    @pytest.mark.parametrize("apply", [False, True],
+                             ids=["agg", "apply"])
+    @pytest.mark.parametrize("segments", [1, 2],
+                             ids=["barriered", "2-segment"])
+    @pytest.mark.parametrize("growth", [None, 2.0],
+                             ids=["exact", "class-padded"])
+    def test_gather_matches_scatter_unpack(self, apply, segments, growth):
+        r = np.random.RandomState(11)
+        tree = {k: jnp.asarray(r.randn(*v).astype(np.float32))
+                for k, v in self.SIZES.items()}
+        errors = jax.tree.map(
+            lambda x: jnp.asarray(r.randn(*x.shape).astype(np.float32))
+            * 0.05, tree)
+        plan = _plan(self.RUNGS)
+        sizes = [math.prod(v) for v in self.SIZES.values()]
+        assert any(n % 1024 for n in sizes)
+        ep = build_exec_plan(plan, sizes, growth=growth, segments=segments)
+        assert ep.segmented == (segments == 2)
+        ranges = (zip(ep.seg_nb, ep.perms) if ep.segmented
+                  else [(ep.total_blocks, ep.perms)])
+        # class padding adds pad rows, each gathering the zero row NB
+        n_pad = sum(int(np.sum(np.asarray(p) == nb))
+                    for nb, ps in ranges for p in ps)
+        assert (n_pad > 0) == (growth is not None)
+        kw = dict(gamma=0.9)
+        if apply:
+            aux = (jax.tree.map(lambda x: x * 0.5, tree),
+                   jax.tree.map(lambda x: x * 0.1, errors),
+                   jax.tree.map(lambda x: jnp.abs(x) * 0.01, tree))
+            scalars = (jnp.float32(3e-4), jnp.float32(0.1),
+                       jnp.float32(0.05))
+
+            def apply_fn(g_rows, aux_rows, sc):
+                p, m, v = aux_rows
+                return adamw.update_rows(p, g_rows, m, v, lr=sc[0],
+                                         bc1=sc[1], bc2=sc[2])
+
+            kw.update(apply_fn=apply_fn, aux=aux, scalars=scalars)
+            got, got_e = S.sync_tree(
+                tree, errors, ep, mesh=None, shardings=None, gamma=0.9,
+                apply_fn=apply_fn, apply_aux=aux, apply_scalars=scalars)
+        else:
+            got, got_e = S.sync_tree(tree, errors, ep, mesh=None,
+                                     shardings=None, gamma=0.9)
+        want, want_e = _scatter_unpack_sync(tree, errors, ep, **kw)
+        for a, b in zip(jax.tree.leaves((got, got_e)),
+                        jax.tree.leaves((want, want_e))):
+            assert a.shape == b.shape and a.dtype == b.dtype
+            np.testing.assert_array_equal(_bits(a), _bits(b))
+
+    @pytest.mark.parametrize("strategy", ["fullsync", "acesync"])
+    def test_step_has_no_scatter_in_the_exchange(self, strategy):
+        cfg = SMOKE_ARCHS["paper-350m"]
+        shape = ShapeConfig("return_path", 32, 2, "train")
+        run = RunConfig(model=cfg, shape=shape, total_steps=30,
+                        warmup_steps=2, lr=1e-3)
+        model = build_model(cfg, run)
+        tr = Trainer(model, run, mesh=None, strategy=strategy)
+        sched = tr.scheduler
+        if strategy == "fullsync":
+            plan = sched.full_plan()
+        else:
+            names = [l.name for l in sched.levels]
+            idx = [names.index("INT8" if g % 2 else "FULL")
+                   for g in range(len(sched.sizes))]
+            plan = sched.plan_from_levels(idx, sync_interval=1,
+                                          adaptive=True)
+        state = tr.init_state(jax.random.PRNGKey(0))
+        tr.step(state, next(TokenPipeline(model, shape, seed=0)), plan,
+                "grad_sync")
+        hlo = tr.step_hlo_text(plan)
+        names = re.findall(r'op_name="([^"]*)"', hlo)
+        assert any("exchange/unpack" in n for n in names)
+        scatters = [line for line in hlo.splitlines()
+                    if re.search(r"\sscatter\(", line)
+                    and re.search(r'op_name="[^"]*exchange/', line)]
+        assert not scatters, scatters[:3]
 
 
 class TestGroupMeta:
