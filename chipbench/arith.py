@@ -1,5 +1,6 @@
-"""The benchmark's yardstick arithmetic: model FLOPs per token, the bytes a
-codec rung must move, and the table of device peaks."""
+"""The benchmark's yardstick arithmetic: model FLOPs per token (from the
+configuration's family module), the bytes a codec rung must move, and the
+table of device peaks."""
 from __future__ import annotations
 
 import json
@@ -7,27 +8,22 @@ import math
 import os
 import re
 
+import loader
+
 HERE = os.path.dirname(os.path.abspath(__file__))
 BLOCK = 1024
 
 
 def param_count(cfg: dict) -> int:
-    """Parameters of the dense decoder LM at the configuration's widths
-    and depth, counting the vocabulary's own rows (not the embedding's
-    padding) once for the tied embedding and head."""
-    L, D, F = cfg["n_layers"], cfg["d_model"], cfg["d_ff"]
-    HD = cfg["n_heads"] * cfg["head_dim"]
-    KD = cfg["n_kv_heads"] * cfg["head_dim"]
-    per_layer = D * HD + 2 * D * KD + HD * D + 3 * D * F + 2 * D
-    return cfg["vocab_size"] * D + L * per_layer + D
+    """Parameters of the configuration's model, as its family counts them
+    (``families/<family>.py``)."""
+    return loader.family(cfg).param_count(cfg)
 
 
 def flops_per_token(cfg: dict, seq_len: int) -> float:
-    """Training FLOPs per token: 6 N for the weights' forward and backward
-    matmuls plus 12 L S (heads x head_dim) for the attention scores and
-    values over a causal-free S x S window (recomputation not counted)."""
-    attn = 12 * cfg["n_layers"] * seq_len * cfg["n_heads"] * cfg["head_dim"]
-    return 6.0 * param_count(cfg) + attn
+    """Training FLOPs per token of the configuration's model at
+    ``seq_len``, as its family counts them."""
+    return loader.family(cfg).flops_per_token(cfg, seq_len)
 
 
 #: bytes of a rung's payload per element and per 1024-element block

@@ -2,7 +2,8 @@
 """The readings that the limits of a cell's ``correct`` are set from.
 
     python3 chipbench/control.py --workload <cell> --seeds 1,2,3 \\
-        [--faults half_batch] [--control-seeds 2] [--out <file.jsonl>]
+        [--faults half_batch,drop_pod] [--control-seeds 2] \\
+        [--out <file.jsonl>]
 
 For each seed, at the cell's own sizes and in one process: the program's
 first steps through the window's own call, compared with the plain
@@ -47,10 +48,12 @@ def readings(cell, cfg, traffic, seed, devices, faults, control=True):
     def ref_run(**kw):
         return run.reference_readings(
             cfg, traffic, run.data_seed(seed), run.weight_key(seed), rungs,
-            omega, n_check, devices[0], **kw)
+            omega, n_check, devices, **kw)
 
     ref = ref_run()
-    rec = {"seed": seed, "rungs": rungs, "program": run.compare(prog, ref)}
+    rec = {"seed": seed, "rungs": rungs,
+           "program": dict(run.compare(prog, ref),
+                           pod_spread=prog["pod_spread"])}
     if not control:
         return rec
     rec["control"] = run.compare(ref_run(rnd=reference.fp8_round), ref)

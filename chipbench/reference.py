@@ -2,19 +2,26 @@
 straightforward ``jax.numpy`` and float32, from the configuration's
 equations alone.  It imports nothing of the program under test.
 
-What it computes, for a configuration file and a traffic file:
+The model is the configuration's family module (``families/<family>.py``:
+its weights, its summed cross-entropy and their gradient); what this file
+adds does not depend on the model.  For a configuration file, a traffic
+file and the family module it computes, for each of ``pods`` replicas:
 
-* the dense decoder LM of the configuration (tied embeddings, RMSNorm with
-  a ``1 + w`` gain, rotary attention over half-split head dims, SwiGLU
-  MLP), its mean token cross-entropy over the embedding's rows, and the
-  gradient;
-* the gradient clipped to a global norm;
-* the exchange of one replica on every step, per parameter leaf on the
-  rung the plan assigns it: error feedback ``ef = g + gamma * e``, the
-  rung's blockwise code (FULL rounds to bfloat16, INT8 / INT4 round to
-  ``absmax / 127`` or ``/ 7`` per 1024-element block), the decoded value
-  weighted by omega as the aggregate and ``ef - decoded`` as the next
-  residual;
+* the gradient of the replica's own rows, clipped to a global norm;
+* with ``sync_interval`` 1 (one replica only), the exchange on every
+  step, per parameter leaf on the rung the plan assigns it: error feedback
+  ``ef = g + gamma * e``, the rung's blockwise code (FULL rounds to
+  bfloat16, INT8 / INT4 round to ``absmax / 127`` or ``/ 7`` per
+  1024-element block), the decoded value weighted by omega as the
+  aggregate and ``ef - decoded`` as the next residual;
+* with ``sync_interval`` H > 1, a local step on every host step (each
+  replica its own rows, clip and AdamW state) and on every H-th the delta
+  exchange: ``ef_p = (theta_p - anchor) + gamma * e_p`` coded per leaf as
+  above, the omega-weighted sum over replicas folded as the program folds
+  it (FULL: a bfloat16 sum of bfloat16 terms; the other rungs, with three
+  replicas or more: each term rounded to ``accum_bits`` fractional bits of
+  int32 fixed point and summed exactly), every replica set to
+  ``anchor + agg`` and the anchor reset;
 * AdamW with a linear-warmup cosine schedule and bias correction.
 
 ``rnd`` is applied to every matmul operand in the forward pass.  The
@@ -24,14 +31,15 @@ precision (float8 with a straight-through gradient).
 from __future__ import annotations
 
 import math
-from typing import Callable, Dict, List, Optional, Sequence
+from typing import Callable, List, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 BLOCK = 1024
-HIGHEST = jax.lax.Precision.HIGHEST
+#: the largest f32 that casts to int32 without overflow (2^31 - 128)
+INT32_SAT = 2147483520.0
 
 
 def identity(x):
@@ -44,115 +52,6 @@ def fp8_round(x):
     scale = jnp.maximum(jnp.max(jnp.abs(x)), 1e-30) / 448.0
     y = (x / scale).astype(jnp.float8_e4m3fn).astype(jnp.float32) * scale
     return x + jax.lax.stop_gradient(y - x)
-
-
-# ---------------------------------------------------------------------------
-# model
-# ---------------------------------------------------------------------------
-
-
-def embedding_rows(cfg: dict) -> int:
-    return int(cfg["embedding_rows"])
-
-
-def param_shapes(cfg: dict) -> Dict:
-    """Leaf shapes of the LM, layers stacked on a leading axis."""
-    L, D, F = cfg["n_layers"], cfg["d_model"], cfg["d_ff"]
-    HD = cfg["n_heads"] * cfg["head_dim"]
-    KD = cfg["n_kv_heads"] * cfg["head_dim"]
-    return {
-        "embed": (embedding_rows(cfg), D),
-        "final_norm": (D,),
-        "blocks": {"slot0": {
-            "attn": {"wq": (L, D, HD), "wk": (L, D, KD), "wv": (L, D, KD),
-                     "wo": (L, HD, D)},
-            "ffn": {"w_gate": (L, D, F), "w_up": (L, D, F),
-                    "w_down": (L, F, D)},
-            "ln1": (L, D), "ln2": (L, D)}},
-    }
-
-
-def init_params(key, cfg: dict):
-    """Seeded weights: matrices normal / sqrt(fan_in), the embedding
-    normal * 0.02, norm gains zero.  Traced once, in float32."""
-    shapes = param_shapes(cfg)
-    paths = jax.tree_util.tree_flatten_with_path(
-        shapes, is_leaf=lambda s: isinstance(s, tuple))[0]
-    keys = jax.random.split(key, len(paths))
-    out = []
-    for k, (path, shape) in zip(keys, paths):
-        name = path[-1].key
-        if name == "embed":
-            out.append(jax.random.normal(k, shape, jnp.float32) * 0.02)
-        elif len(shape) == 3:
-            out.append(jax.random.normal(k, shape, jnp.float32)
-                       / math.sqrt(shape[-2]))
-        else:
-            out.append(jnp.zeros(shape, jnp.float32))     # norm gains
-    tdef = jax.tree.structure(shapes, is_leaf=lambda s: isinstance(s, tuple))
-    return jax.tree.unflatten(tdef, out)
-
-
-def _rms(x, w, eps):
-    var = jnp.mean(x * x, axis=-1, keepdims=True)
-    return x * jax.lax.rsqrt(var + eps) * (1.0 + w)
-
-
-def _rope(x, theta):
-    S, dh = x.shape[1], x.shape[-1]
-    half = dh // 2
-    freqs = jnp.exp(-math.log(theta) * jnp.arange(half, dtype=jnp.float32)
-                    / half)
-    ang = jnp.arange(S, dtype=jnp.float32)[:, None] * freqs   # (S, half)
-    cos, sin = jnp.cos(ang)[:, None, :], jnp.sin(ang)[:, None, :]
-    x1, x2 = x[..., :half], x[..., half:]
-    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
-
-
-def nll_sum(params, tokens, labels, cfg: dict, rnd: Callable = identity):
-    """Summed next-token cross-entropy of a block of rows."""
-    H, KV, Dh = cfg["n_heads"], cfg["n_kv_heads"], cfg["head_dim"]
-    eps, theta = cfg["rms_eps"], cfg["rope_theta"]
-
-    def mm(a, b):
-        return jnp.matmul(rnd(a), rnd(b), precision=HIGHEST)
-
-    emb = params["embed"]
-    x = emb[tokens]
-    B, S, _ = x.shape
-    causal = jnp.tril(jnp.ones((S, S), bool))
-
-    def layer(x, p):
-        h = _rms(x, p["ln1"], eps)
-        q = _rope(mm(h, p["attn"]["wq"]).reshape(B, S, H, Dh), theta)
-        k = _rope(mm(h, p["attn"]["wk"]).reshape(B, S, KV, Dh), theta)
-        v = mm(h, p["attn"]["wv"]).reshape(B, S, KV, Dh)
-        if H != KV:
-            k = jnp.repeat(k, H // KV, axis=2)
-            v = jnp.repeat(v, H // KV, axis=2)
-        s = jnp.einsum("bqhd,bkhd->bhqk", rnd(q), rnd(k),
-                       precision=HIGHEST) / math.sqrt(Dh)
-        s = jnp.where(causal, s, -jnp.inf)
-        a = jax.nn.softmax(s, axis=-1)
-        o = jnp.einsum("bhqk,bkhd->bqhd", rnd(a), rnd(v), precision=HIGHEST)
-        x = x + mm(o.reshape(B, S, H * Dh), p["attn"]["wo"])
-        h = _rms(x, p["ln2"], eps)
-        f = jax.nn.silu(mm(h, p["ffn"]["w_gate"])) * mm(h, p["ffn"]["w_up"])
-        return x + mm(f, p["ffn"]["w_down"]), None
-
-    x, _ = jax.lax.scan(jax.checkpoint(layer), x, params["blocks"]["slot0"])
-    x = _rms(x, params["final_norm"], eps)
-
-    def chunk_nll(xc, lc):
-        logits = mm(xc, emb.T)
-        lse = jax.nn.logsumexp(logits, axis=-1)
-        gold = jnp.take_along_axis(logits, lc[..., None], axis=-1)[..., 0]
-        return jnp.sum(lse - gold)
-
-    # one batch row at a time keeps the (S, rows) logits small
-    tot = jax.lax.map(lambda xl: jax.checkpoint(chunk_nll)(*xl),
-                      (x, labels))
-    return jnp.sum(tot)
 
 
 # ---------------------------------------------------------------------------
@@ -179,6 +78,13 @@ def rung_code(rung: str, ef):
                         1e-30)
     q = jnp.clip(jnp.round(b / scale), -top, top)
     return (q * scale).reshape(-1)[:ef.size].reshape(ef.shape)
+
+
+def fixed_point(x, bits: int):
+    """``x`` as int32 fixed point with ``bits`` fractional bits:
+    rounded to nearest even, saturating at the int32 range."""
+    s = jnp.round(x * jnp.float32(2.0 ** bits))
+    return jnp.clip(s, -INT32_SAT, INT32_SAT).astype(jnp.int32)
 
 
 # ---------------------------------------------------------------------------
@@ -215,108 +121,212 @@ def adamw(p, g, m, v, lr, bc1, bc2, *, beta1, beta2, weight_decay):
 # ---------------------------------------------------------------------------
 
 
-def _norm(x) -> float:
-    return float(jnp.sqrt(jnp.sum(jnp.square(x))))
-
-
 class Reference:
-    """Steps one replica of the configuration from seeded weights.
+    """Steps ``pods`` replicas of the configuration from seeded weights.
 
-    ``rungs`` maps each leaf (in flattening order) to its rung name and
-    ``omega`` weights the replica's decoded gradient.  The loss is the
-    mean over the batch, its gradient summed over blocks of
-    ``ROWS_PER_BLOCK`` rows so that a large batch fits.
+    ``family`` is the configuration's family module; ``rungs`` maps each
+    leaf (in flattening order) to its rung name and ``omega`` weights each
+    replica's decoded term.  A replica's loss is the mean over its rows,
+    its gradient summed over blocks of ``ROWS_PER_BLOCK`` rows so that a
+    large batch fits.  Replica ``p`` lives on ``devices[p]`` (round robin
+    over the devices given), so that the replicas' steps run side by side
+    and each holds one replica's state.
 
     ``fault`` plants one of the faults the check must catch, for the
-    control test: ``"half_batch"`` (the loss over the first half of the
-    rows)."""
+    control test: ``"half_batch"`` (each replica's loss over the first
+    half of its rows), ``"drop_pod"`` (the last replica's term left out of
+    every delta exchange), ``"no_exchange"`` (every delta exchange left
+    out, each replica keeping its own parameters)."""
 
     ROWS_PER_BLOCK = 8
 
-    def __init__(self, cfg: dict, opt: dict, *, rungs: Sequence[str],
-                 omega: Sequence[float], device=None,
+    def __init__(self, cfg: dict, opt: dict, family, *,
+                 rungs: Sequence[str], omega: Sequence[float],
+                 pods: int = 1, sync_interval: int = 1, devices=None,
                  rnd: Callable = identity, fault: Optional[str] = None):
+        if sync_interval == 1 and pods != 1:
+            raise ValueError("a gradient exchange on every step is "
+                             "followed for one replica only")
         self.cfg, self.opt = cfg, opt
         self.rungs = list(rungs)
-        self.omega = float(omega[0])
-        self.device = device or jax.devices()[0]
+        self.omega = [float(w) for w in omega]
+        self.pods, self.H = pods, sync_interval
+        devices = list(devices or jax.devices())
+        self.devices = [devices[p % len(devices)] for p in range(pods)]
         self.fault = fault
-        grad = jax.value_and_grad(lambda p, t, l: nll_sum(p, t, l, cfg, rnd))
+        grad = jax.value_and_grad(
+            lambda p, t, l: family.nll_sum(p, t, l, cfg, rnd))
 
         def accumulate(acc, params, tok, lab):
             loss, g = grad(params, tok, lab)
             return (acc[0] + loss, jax.tree.map(jnp.add, acc[1], g))
         self._accumulate = jax.jit(accumulate, donate_argnums=(0,))
-        self._init = jax.jit(lambda k: init_params(k, cfg))
+        self._init = jax.jit(lambda k: family.init_params(k, cfg))
+        adam = dict(beta1=opt["beta1"], beta2=opt["beta2"],
+                    weight_decay=opt["weight_decay"])
 
         def apply(p, g, m, v, e, scale, lr, bc1, bc2, *, rung):
             ef = g * scale + opt["gamma"] * e
             d = rung_code(rung, ef)
-            e, g = ef - d, d * self.omega
-            p, m, v = adamw(p, g, m, v, lr, bc1, bc2, beta1=opt["beta1"],
-                            beta2=opt["beta2"],
-                            weight_decay=opt["weight_decay"])
+            e, g = ef - d, d * self.omega[0]
+            p, m, v = adamw(p, g, m, v, lr, bc1, bc2, **adam)
             return p, m, v, e, jnp.sqrt(jnp.sum(jnp.square(g)))
         self._apply = jax.jit(apply, static_argnames=("rung",),
                               donate_argnums=(0, 2, 3, 4))
+
+        def local(p, g, m, v, scale, lr, bc1, bc2):
+            g = g * scale
+            p, m, v = adamw(p, g, m, v, lr, bc1, bc2, **adam)
+            return p, m, v, jnp.sqrt(jnp.sum(jnp.square(g)))
+        self._local = jax.jit(local, donate_argnums=(0, 2, 3))
+
+        def encode(p, a, e, *, rung):
+            ef = (p - a) + opt["gamma"] * e
+            d = rung_code(rung, ef)
+            return d, ef - d
+        self._encode = jax.jit(encode, static_argnames=("rung",),
+                               donate_argnums=(2,))
+        bits = opt["accum_bits"]
+
+        def term(d, w, *, kind):
+            if kind == "bf16":
+                return (d * w).astype(jnp.bfloat16)
+            if kind == "fixed":
+                return fixed_point(w * d, bits)
+            return w * d
+        self._term = jax.jit(term, static_argnames=("kind",))
+
+        def fold(terms, *, kind):
+            if kind == "bf16":
+                tot = sum(t.astype(jnp.float32) for t in terms)
+                return tot.astype(jnp.bfloat16).astype(jnp.float32)
+            if kind == "fixed":
+                tot = sum(terms[1:], terms[0])
+                return tot.astype(jnp.float32) * jnp.float32(2.0 ** -bits)
+            return sum(terms[1:], terms[0])
+        self._fold = jax.jit(fold, static_argnames=("kind",))
+        self._add = jax.jit(jnp.add)
         self._sq = jax.jit(lambda t: sum(jnp.sum(jnp.square(x))
                                          for x in jax.tree.leaves(t)))
         self._change = jax.jit(lambda p, k: [
             jnp.sqrt(jnp.sum(jnp.square(a - b)))
             for a, b in zip(jax.tree.leaves(p),
-                            jax.tree.leaves(init_params(k, cfg)))])
+                            jax.tree.leaves(family.init_params(k, cfg)))])
 
-    def _grads(self, params, tok, lab):
-        """(mean loss, gradient of the mean loss) over all rows."""
+    def _grads(self, params, tok, lab, device):
+        """(summed loss, summed gradient, rows x tokens) of one replica's
+        rows, dispatched on ``device`` and not waited for."""
         if self.fault == "half_batch":
             tok, lab = tok[:len(tok) // 2], lab[:len(lab) // 2]
         acc = (jnp.zeros((), jnp.float32),
                jax.tree.map(jnp.zeros_like, params))
-        acc = jax.device_put(acc, self.device)
+        acc = jax.device_put(acc, device)
         for r in range(0, len(tok), self.ROWS_PER_BLOCK):
             rows = slice(r, r + self.ROWS_PER_BLOCK)
             acc = self._accumulate(
-                acc, params, jax.device_put(jnp.asarray(tok[rows]),
-                                            self.device),
-                jax.device_put(jnp.asarray(lab[rows]), self.device))
-        n = float(tok.size)
-        return float(acc[0]) / n, acc[1], n
+                acc, params, jax.device_put(jnp.asarray(tok[rows]), device),
+                jax.device_put(jnp.asarray(lab[rows]), device))
+        return acc[0], acc[1], float(tok.size)
+
+    def _fold_kind(self, rung: str) -> str:
+        """How the program folds a rung's terms over the replicas: one
+        replica takes its own decoded value; FULL is a bfloat16 sum; the
+        other rungs fold in int32 fixed point from three replicas on, and
+        two in float (a sum of two terms is the same in either order)."""
+        if self.pods == 1:
+            return "float"
+        if rung == "FULL":
+            return "bf16"
+        return "fixed" if self.pods >= 3 else "float"
+
+    def _exchange(self, params, anchor, err):
+        """The delta exchange, leaf by leaf: every replica's error-fed
+        delta coded on its rung, the omega-weighted terms folded on the
+        first replica's device, every replica and the anchor set to
+        ``anchor + agg``."""
+        devs, P = self.devices, self.pods
+        # one replica takes its own decoded value whatever the plan's omega
+        omega = self.omega if P > 1 else [1.0]
+        keep = range(P - 1) if self.fault == "drop_pod" else range(P)
+        for i, rung in enumerate(self.rungs):
+            kind = self._fold_kind(rung)
+            terms = []
+            for p in range(P):
+                d, err[p][i] = self._encode(params[p][i], anchor[p][i],
+                                            err[p][i], rung=rung)
+                if p in keep:
+                    terms.append(jax.device_put(
+                        self._term(d, np.float32(omega[p]), kind=kind),
+                        devs[0]))
+            agg = self._fold(terms, kind=kind)
+            for p in range(P):
+                a = jax.device_put(agg, devs[p])
+                # two buffers: the replica's step donates its parameters
+                params[p][i] = self._add(anchor[p][i], a)
+                anchor[p][i] = self._add(anchor[p][i], a)
 
     def run(self, key, batches: List, n_steps: int):
-        """``batches[s]``: the (tokens, labels) numpy rows of host step s;
-        ``key`` the weights' PRNG key.  Returns per-step losses and
-        pre-clip gradient norms, and per leaf the norm of the first
-        gradient the optimizer received and of the parameters' change
-        over ``n_steps``."""
-        key = jax.device_put(key, self.device)
-        params = self._init(key)
-        tdef = jax.tree.structure(params)
-        zeros = lambda: [jnp.zeros_like(x) for x in jax.tree.leaves(params)]
-        m, v = zeros(), zeros()
-        err = zeros()
+        """``batches[s]``: the (tokens, labels) numpy rows of host step s,
+        replica ``p`` taking the ``p``-th equal share of the rows; ``key``
+        the weights' PRNG key.  Returns per-step losses and pre-clip
+        gradient norms (each the mean over replicas), and per leaf of
+        replica 0 the norm of the first gradient the optimizer received
+        and of the parameters' change over ``n_steps``."""
+        P, devs = self.pods, self.devices
+        params, tdef = [], None
+        for dev in devs:
+            tree = self._init(jax.device_put(key, dev))
+            tdef = jax.tree.structure(tree)
+            params.append(jax.tree.leaves(tree))
+        zeros = lambda p: [jnp.zeros_like(x) for x in params[p]]
+        m = [zeros(p) for p in range(P)]
+        v = [zeros(p) for p in range(P)]
+        err = [zeros(p) for p in range(P)]
+        anchor = ([[self._add(x, np.float32(0)) for x in params[p]]
+                   for p in range(P)] if self.H > 1 else None)
         losses, gnorms, first = [], [], None
         for s in range(n_steps):
             tok, lab = batches[s]
-            loss, grads, n = self._grads(params, tok, lab)
-            # the summed gradient's norm, scaled to the mean's
-            norm = math.sqrt(float(self._sq(grads))) / n
-            losses.append(loss)
-            gnorms.append(norm)
-            scale = np.float32(min(1.0, self.opt["grad_clip"]
-                                   / max(norm, 1e-12)) / n)
+            rows = len(tok) // P
+            out = [self._grads(jax.tree.unflatten(tdef, params[p]),
+                               tok[p * rows:(p + 1) * rows],
+                               lab[p * rows:(p + 1) * rows], devs[p])
+                   for p in range(P)]
+            sqs = [self._sq(g) for _, g, _ in out]
+            step_loss, step_norm, scales = [], [], []
+            for (loss, _, n), sq in zip(out, sqs):
+                # the summed gradient's norm, scaled to the mean's
+                norm = math.sqrt(float(sq)) / n
+                step_loss.append(float(loss) / n)
+                step_norm.append(norm)
+                scales.append(np.float32(min(1.0, self.opt["grad_clip"]
+                                             / max(norm, 1e-12)) / n))
+            losses.append(sum(step_loss) / P)
+            gnorms.append(sum(step_norm) / P)
             scalars = adam_scalars(s, self.opt)
-            p, g = jax.tree.leaves(params), jax.tree.leaves(grads)
-            del params, grads
-            applied = []
-            for i in range(len(p)):
-                p[i], m[i], v[i], err[i], gn = self._apply(
-                    p[i], g[i], m[i], v[i], err[i], scale, *scalars,
-                    rung=self.rungs[i])
-                g[i] = None
-                applied.append(gn)
-            if first is None:
-                first = [float(x) for x in applied]
-            params = jax.tree.unflatten(tdef, p)
-        change = [float(x) for x in self._change(params, key)]
+            for p in range(P):
+                g = jax.tree.leaves(out[p][1])
+                out[p] = None
+                applied = []
+                for i in range(len(g)):
+                    if self.H == 1:
+                        (params[p][i], m[p][i], v[p][i], err[p][i],
+                         gn) = self._apply(params[p][i], g[i], m[p][i],
+                                           v[p][i], err[p][i], scales[p],
+                                           *scalars, rung=self.rungs[i])
+                    else:
+                        params[p][i], m[p][i], v[p][i], gn = self._local(
+                            params[p][i], g[i], m[p][i], v[p][i],
+                            scales[p], *scalars)
+                    g[i] = None
+                    applied.append(gn)
+                if first is None:
+                    first = [float(x) for x in applied]
+            if (self.H > 1 and (s + 1) % self.H == 0
+                    and self.fault != "no_exchange"):
+                self._exchange(params, anchor, err)
+        change = [float(x) for x in self._change(
+            jax.tree.unflatten(tdef, params[0]),
+            jax.device_put(key, devs[0]))]
         return {"losses": losses, "grad_norms": gnorms, "first": first,
                 "change": change}

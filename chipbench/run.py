@@ -5,13 +5,18 @@
         --trace <0|1>
 
 A cell (an entry of ``workloads`` in ``BENCHMARK.json``) names a
-configuration (``chipbench/configs/<config>.json``: the model's sizes) and
-a traffic mix (``chipbench/traffic/<traffic>.json``: the training job's
-batch, sequence, strategy and exchange settings).  The run
+configuration (``chipbench/configs/<config>.json``: the model's sizes and
+its ``family``, whose reference model and counts are
+``chipbench/families/<family>.py``) and a traffic mix
+(``chipbench/traffic/<traffic>.json``: the training job's batch,
+sequence, strategy, pods, sync interval and exchange settings).  Each pod
+is one edge node's whole replica on one chip.  The run
 
-1. builds the program's ``TrainSession`` from them, makes the weights on
-   the device from ``--seed`` in one jitted call, seeds the token stream
-   from ``--seed`` and the link telemetry from the job's own fixed seed;
+1. builds the program's ``TrainSession`` from them (on a ``(pods, 1, 1)``
+   ``("pod", "data", "model")`` mesh over the cell's chips where there is
+   more than one pod), makes the weights on the devices from ``--seed`` in
+   one jitted call, seeds the token stream from ``--seed`` and the link
+   telemetry from the job's own fixed seed;
 2. drives the first steps through the window's own call
    (``TrainLoop.run_steps``) and reads what the comparison needs, then
    warms every executable the window uses (the first device replan's
@@ -33,8 +38,8 @@ configuration's ``rehearsal`` sizes and prints no device metric.
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import gc
-import importlib.util
 import json
 import math
 import os
@@ -49,14 +54,17 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(HERE)
 if HERE not in sys.path:
     sys.path.insert(0, HERE)
+import loader  # noqa: E402
+
 REHEARSAL_ENV = "CHIPBENCH_REHEARSAL"
 #: run-time outputs of the benchmark inside the checkout (gitignored)
 WORK = os.path.join(ROOT, ".chipbench")
 
-#: keys of a configuration file that the program's ModelConfig takes
-MODEL_KEYS = ("family", "n_layers", "d_model", "n_heads", "n_kv_heads",
-              "head_dim", "d_ff", "vocab_size", "tie_embeddings",
-              "rope_theta", "rms_eps", "dtype", "param_dtype")
+#: keys of a configuration file that are the harness's own; every other
+#: key has to be a field of the program's ModelConfig, which gets it
+HARNESS_KEYS = ("name", "source", "batch_per_node", "embedding_rows",
+                "published", "reduced", "assumed", "deployment",
+                "rehearsal")
 
 
 def log(msg: str) -> None:
@@ -87,10 +95,34 @@ def load_cell(name: str, rehearsal: bool):
     cell = cells[name]
     cfg = load_json(HERE, "configs", cell["config"] + ".json")
     traffic = load_json(HERE, "traffic", cell["traffic"] + ".json")
+    loader.family(cfg)          # a family without a module stops here
+    if pods_of(traffic) != cell["chips"]:
+        raise SystemExit(f"traffic {cell['traffic']!r} runs "
+                         f"{pods_of(traffic)} pod(s): cell {name!r} asks "
+                         f"for {cell['chips']} chip(s), one pod each")
     if rehearsal:
         cfg = dict(cfg, **cfg["rehearsal"])
         traffic = dict(traffic, **traffic["rehearsal"])
     return cell, bench, cfg, traffic
+
+
+def pods_of(traffic: dict) -> int:
+    """Edge nodes of the job, one whole replica per chip (default 1)."""
+    return int(traffic.get("pods", 1))
+
+
+def model_config(cfg: dict):
+    """The program's ModelConfig with every key of the configuration file
+    that is one of its fields; a key that is neither a field nor the
+    harness's own is refused by name."""
+    from repro.configs.base import ModelConfig
+    fields = {f.name for f in dataclasses.fields(ModelConfig)}
+    unknown = sorted(set(cfg) - fields - set(HARNESS_KEYS))
+    if unknown:
+        raise ValueError(f"configuration {cfg.get('name')!r}: keys "
+                         f"{unknown} are neither ModelConfig fields nor "
+                         f"the harness's own {HARNESS_KEYS}")
+    return ModelConfig(**{k: v for k, v in cfg.items() if k in fields})
 
 
 def cell_limits(name: str, rehearsal: bool) -> dict:
@@ -237,23 +269,24 @@ class Cell:
                  devices, trace: bool):
         import jax
         import jax.numpy as jnp
-        import reference
-        from repro.configs.base import (ACESyncConfig, ModelConfig,
-                                        RunConfig, ShapeConfig)
+        from repro.configs.base import ACESyncConfig, RunConfig, ShapeConfig
         from repro.data.pipeline import TokenPipeline
+        from repro.launch.mesh import make_mesh
         from repro.launch.session import TrainSession
         from repro.models.registry import build_model
 
+        family = loader.family(cfg)
         self.traffic = traffic
-        self.global_batch = cfg["batch_per_node"]
+        self.pods = pods = pods_of(traffic)
+        # one host step: every pod's rows, pod p taking the p-th share
+        self.global_batch = pods * cfg["batch_per_node"]
         self.seq_len = traffic["seq_len"]
         opt, ex = traffic["optimizer"], traffic["exchange"]
         H = traffic["sync_interval"]
-        if H != 1 or cell["chips"] != 1:
-            raise ValueError("the reference follows one replica with a "
-                             "gradient exchange on every step")
-        model_cfg = ModelConfig(name=cfg["name"],
-                                **{k: cfg[k] for k in MODEL_KEYS})
+        if H == 1 and pods != 1:
+            raise ValueError("a gradient exchange on every step is "
+                             "followed for one pod only")
+        model_cfg = model_config(cfg)
         if model_cfg.padded_vocab != cfg["embedding_rows"]:
             raise ValueError("embedding_rows does not match the program's "
                              "padded vocabulary")
@@ -271,41 +304,54 @@ class Cell:
             ckpt_dir=os.path.join(WORK, "ckpt"),
             seed=traffic["estimator_seed"])
         model = build_model(model_cfg, run_cfg)
-        self.sess = TrainSession(model, run_cfg,
+        # one pod is the program's path without a mesh; more are a pod
+        # mesh, each pod's state on its own chip
+        mesh = (make_mesh((pods, 1, 1), ("pod", "data", "model"),
+                          devices=devices) if pods > 1 else None)
+        self.sess = TrainSession(model, run_cfg, mesh=mesh,
                                  strategy=traffic["strategy"],
                                  n_edge_devices=traffic["n_edge_devices"],
-                                 seed=traffic["telemetry_seed"])
+                                 seed=traffic["telemetry_seed"],
+                                 elastic=False)
         self.sess.pipeline = TokenPipeline(model, shape,
                                            seed=data_seed(seed))
         self.loop = self.sess.loop
         trainer = self.loop.trainer
         specs = model.param_specs()
-        shapes = reference.param_shapes(cfg)
+        shapes = family.param_shapes(cfg)
         if jax.tree.map(lambda s: tuple(s.shape), specs) != jax.tree.map(
                 tuple, shapes, is_leaf=lambda s: isinstance(s, tuple)):
             raise ValueError("the configuration's parameter shapes differ "
                              "from the program's")
 
-        # the weights: one jitted call from the seed, on the device, in
-        # the program's state layout (a replica axis of one leading)
+        # the weights: one jitted call from the seed, on the devices, in
+        # the program's state layout (a leading pod axis, each pod's
+        # replica on its own chip)
         est_key = jax.random.PRNGKey(traffic["estimator_seed"])
         self.weight_key = weight_key(seed)
 
         def build(key):
             st = trainer.init_state(est_key)
-            params = jax.tree.map(lambda x: x[None],
-                                  reference.init_params(key, cfg))
+            params = jax.tree.map(
+                lambda x: jnp.broadcast_to(x[None], (pods,) + x.shape),
+                family.init_params(key, cfg))
             st["params"] = params
             if "anchor" in st:
                 st["anchor"] = jax.tree.map(jnp.copy, params)
             return st
 
-        self.state = jax.jit(build)(self.weight_key)
+        shardings = trainer.state_shardings() if mesh is not None else None
+        self.state = jax.jit(build, out_shardings=shardings)(
+            self.weight_key)
         # the parameters' change from the seeded weights, leaf by leaf
+        # (pod 0)
         self.change_norms = jax.jit(lambda p, key: [
             jnp.sqrt(jnp.sum(jnp.square(a[0] - b))) for a, b in zip(
                 jax.tree.leaves(p),
-                jax.tree.leaves(reference.init_params(key, cfg)))])
+                jax.tree.leaves(family.init_params(key, cfg)))])
+        # the largest |theta_p - theta_0| over pods and leaves
+        self.spread = jax.jit(lambda p: jnp.max(jnp.stack(
+            [jnp.max(jnp.abs(x - x[:1])) for x in jax.tree.leaves(p)])))
 
         # spans around the calls into each layer, step completions
         self.spans = Spans(annotate=trace)
@@ -340,9 +386,10 @@ class Cell:
 
     def first_steps(self, n: int) -> dict:
         """Drive the first ``n`` host steps through ``run_steps`` and read
-        the losses, pre-clip gradient norms, the first gradient as the
-        optimizer got it (from its first moment) and the parameters'
-        change."""
+        the losses and pre-clip gradient norms (each the mean over pods),
+        pod 0's first gradient as the optimizer got it (from its first
+        moment) and its parameters' change, and the pods' spread after
+        the last of them."""
         beta1 = self.traffic["optimizer"]["beta1"]
         state = self.state
         self.state = None
@@ -355,7 +402,9 @@ class Cell:
         change = self.change_norms(state["params"], self.weight_key)
         out = {"losses": [h["loss"] for h in hist],
                "grad_norms": [h["grad_norm"] for h in hist],
-               "first": first, "change": [float(x) for x in change]}
+               "first": first, "change": [float(x) for x in change],
+               "pod_spread": (float(self.spread(state["params"]))
+                              if self.pods > 1 else 0.0)}
         self.state = state
         return out
 
@@ -425,16 +474,22 @@ def compare(prog: dict, ref: dict) -> dict:
 
 
 def reference_readings(cfg, traffic, data_seed, weight_key, rungs, omega,
-                       n_steps, device, rnd=None, fault=None):
+                       n_steps, devices, rnd=None, fault=None):
+    """The plain reference's readings of the first ``n_steps`` host steps
+    (``reference.Reference.run``), each pod's replica on its own device of
+    ``devices``."""
     import reference
     from tokens import TokenRows
     opt = dict(traffic["optimizer"], **traffic["exchange"])
+    pods = pods_of(traffic)
     rows = TokenRows(data_seed, cfg["vocab_size"])
-    batches = [rows.batch(s, cfg["batch_per_node"], traffic["seq_len"])
-               for s in range(n_steps)]
-    ref = reference.Reference(cfg, opt, rungs=rungs, omega=omega,
-                              device=device, rnd=rnd or reference.identity,
-                              fault=fault)
+    batches = [rows.batch(s, pods * cfg["batch_per_node"],
+                          traffic["seq_len"]) for s in range(n_steps)]
+    ref = reference.Reference(cfg, opt, loader.family(cfg), rungs=rungs,
+                              omega=omega, pods=pods,
+                              sync_interval=traffic["sync_interval"],
+                              devices=devices,
+                              rnd=rnd or reference.identity, fault=fault)
     import jax
     with jax.default_matmul_precision("highest"):
         return ref.run(weight_key, batches, n_steps)
@@ -449,21 +504,12 @@ def judge(numbers: dict, limits: dict) -> bool:
 # ---------------------------------------------------------------------------
 
 
-def load_reader(name: str):
-    path = os.path.join(HERE, "metrics", name + ".py")
-    spec = importlib.util.spec_from_file_location(
-        "chipbench_metric_" + name.replace(".", "_"), path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
-
-
 def per_layer(bench: dict, cell_name: str, ctx: dict) -> dict:
     out = {}
     for m in bench["per_layer"]:
         if cell_name not in m.get("workloads", [cell_name]):
             continue
-        value = load_reader(m["name"])(ctx)
+        value = loader.reader(m["name"])(ctx)
         if value is not None:
             out[m["name"]] = {"value": value, "unit": m["unit"]}
     return out
@@ -486,9 +532,15 @@ def parse_args(argv=None):
 def open_devices(chips: int, rehearsal: bool):
     """The cell's devices, with the program importable and JAX's
     persistent compilation cache on: ``(devices, cache directory)``.
-    Exits non-zero without the program or without the chips."""
+    Exits non-zero without the program or without the chips.  A
+    rehearsal runs on as many host-platform CPU devices as the cell has
+    chips."""
     if rehearsal:
         os.environ["JAX_PLATFORMS"] = "cpu"
+        if chips > 1:
+            os.environ["XLA_FLAGS"] = (
+                os.environ.get("XLA_FLAGS", "") + " --xla_force_host_"
+                f"platform_device_count={chips}").strip()
     if not os.path.isdir(os.path.join(ROOT, "src", "repro")):
         log("[setup] the program (src/repro) is not in this checkout")
         sys.exit(3)
@@ -587,9 +639,10 @@ def main(argv=None) -> int:
     t_ref = time.perf_counter()
     ref = reference_readings(cfg, traffic, data_seed(args.seed),
                              weight_key(args.seed), rungs, omega, n_check,
-                             devices[0])
+                             devices)
     log(f"[check] reference_s={time.perf_counter() - t_ref!r}")
-    numbers = dict(compare(prog, ref), plan_swaps=plan_swaps)
+    numbers = dict(compare(prog, ref), plan_swaps=plan_swaps,
+                   pod_spread=prog["pod_spread"])
     correct = judge(numbers, limits)
     log(f"[check] program losses={prog['losses']} reference "
         f"losses={ref['losses']}")

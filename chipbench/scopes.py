@@ -16,10 +16,13 @@ metadata.  This module joins the two:
    instruction of a called computation (a while body) taking its caller's
    path in front of its own;
 2. it maps each instruction to a phase by :func:`phase_of`;
-3. it keeps the ``XLA Ops`` events nested in the step module's
-   ``XLA Modules`` events (the step module: the one with the most device
-   time in the window) and sums their self time per phase over the
-   window, in the trace's clock (``tracereduce.clock_offset``).
+3. it keeps the ``XLA Ops`` events nested in the step modules'
+   ``XLA Modules`` events (the step modules: the one with the most device
+   time in the window and every other compiled from the same function,
+   such as a pod cell's local and delta-exchange steps) and sums their
+   self time per phase over the window, in the trace's clock
+   (``tracereduce.clock_offset``), and apart from the phases the self
+   time of their collective operations.
 
 A program without the scopes reads no phase at all; the readers then
 report nothing.
@@ -309,10 +312,38 @@ def step_module(modules: dict, lo: float, hi: float):
     return max(total, key=total.get) if total else None
 
 
+def module_base(module: str) -> str:
+    """``jit_wrapped`` of ``jit_wrapped(12)``: the function a module was
+    compiled from, without the program's id."""
+    return module.split("(", 1)[0]
+
+
+def step_modules(modules: dict, lo: float, hi: float):
+    """The step module and every module compiled from the same function
+    that ran in [lo, hi] (one per step kind), the step module first."""
+    first = step_module(modules, lo, hi)
+    if first is None:
+        return []
+    others = sorted({name for mods in modules.values()
+                     for name, s, e in mods
+                     if name != first and module_base(name)
+                     == module_base(first) and e > lo and s < hi})
+    return [first] + others
+
+
+def is_collective(hlo: str) -> bool:
+    """Whether an op moves data between chips: by its opcode, or by its
+    name where the compiler wrapped the collective in another op."""
+    name = tracereduce.short_name(hlo)
+    return tracereduce.is_collective(hlo) or any(
+        name.startswith(w) for w in tracereduce.COLLECTIVE_WORDS)
+
+
 def phase_seconds(evs, op_names: dict, lo: float, hi: float):
     """Self seconds in [lo, hi] of the op events ``evs`` (sorted by start)
-    per phase, and per exchange sub-scope."""
+    per phase, per exchange sub-scope, and of the collective ops."""
     per, parts = defaultdict(float), defaultdict(float)
+    coll = 0.0
     for hlo, s, e, own in tracereduce.self_times(evs):
         c = tracereduce.clip([(s, e)], lo, hi)
         if not c or e <= s:
@@ -323,28 +354,35 @@ def phase_seconds(evs, op_names: dict, lo: float, hi: float):
         per[phase] += t
         if phase == "exchange":
             parts[exchange_part(op_name)] += t
-    return per, parts
+        if is_collective(hlo):
+            coll += t
+    return per, parts, coll
 
 
 def reduce(tr: dict, lo: float, hi: float):
-    """Per-phase and per-sub-scope self seconds of the step module in the
-    window [lo, hi] (trace clock), averaged over the device planes, and
-    the step module's name."""
-    module = step_module(tr["modules"], lo, hi)
-    if module is None:
+    """Per-phase and per-sub-scope self seconds of the step modules in the
+    window [lo, hi] (trace clock), and the self seconds of their
+    collective ops, averaged over the device planes, and the step
+    module's name."""
+    modules = step_modules(tr["modules"], lo, hi)
+    if not modules:
         return None
-    names = tr["hlo"].get(module, {})
     n = max(len(tr["ops"]), 1)
     per, parts = defaultdict(float), defaultdict(float)
-    for plane, evs in tr["ops"].items():
-        p, q = phase_seconds(
-            module_ops(tr["modules"].get(plane, []), evs, module), names,
-            lo, hi)
-        for k, v in p.items():
-            per[k] += v / n
-        for k, v in q.items():
-            parts[k] += v / n
-    return {"module": module, "phases": dict(per), "exchange": dict(parts)}
+    coll = 0.0
+    for module in modules:
+        names = tr["hlo"].get(module, {})
+        for plane, evs in tr["ops"].items():
+            p, q, c = phase_seconds(
+                module_ops(tr["modules"].get(plane, []), evs, module),
+                names, lo, hi)
+            for k, v in p.items():
+                per[k] += v / n
+            for k, v in q.items():
+                parts[k] += v / n
+            coll += c / n
+    return {"module": modules[0], "modules": modules, "phases": dict(per),
+            "exchange": dict(parts), "collective": coll}
 
 
 # ---------------------------------------------------------------------------
@@ -368,9 +406,10 @@ _CACHE: dict = {}
 
 
 def reading(ctx: dict):
-    """Device ms per host step of each phase of the step module in the
-    traced window (``exchange`` per exchanging step), or None where the
-    trace or the program's scopes give nothing.  Read once per run; a
+    """Device ms per host step of each phase of the step modules in the
+    traced window (``exchange`` and ``collective``, the collective ops'
+    self time, per exchanging step), or None where the trace or the
+    program's scopes give nothing.  Read once per run; a
     trace it cannot read is logged and reads as nothing, never stopping
     the run."""
     key = (ctx["t0"], ctx["t_end"])
@@ -400,10 +439,12 @@ def _read(ctx: dict):
           for p in PHASES + (UNSCOPED,)}
     ms["exchange"] = (1e3 * red["phases"].get("exchange", 0.0) / syncs
                       if syncs else None)
+    ms["collective"] = 1e3 * red["collective"] / syncs if syncs else None
     total = 1e3 * sum(red["phases"].values()) / steps
     parts = " ".join(f"{k}={1e3 * v / max(syncs, 1):.3f}"
                      for k, v in sorted(red["exchange"].items()))
-    print(f"[trace] scopes module={red['module']} step_self_ms={total:.3f} "
+    print(f"[trace] scopes modules={red['modules']} "
+          f"step_self_ms={total:.3f} "
           + " ".join(f"{p}={ms[p]}" for p in PHASES + (UNSCOPED,))
           + f" | exchange ms per exchanging step: {parts}",
           file=sys.stderr, flush=True)

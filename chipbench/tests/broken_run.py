@@ -8,7 +8,11 @@ before its first step:
 
 * ``unchanged``: every step returns the state it was given;
 * ``half_batch``: the loss is taken over the first half of the rows,
-  its mean over those.
+  its mean over those;
+* ``drop_pod`` (a pod cell): the last pod's delta is left out of every
+  delta exchange's aggregate (its omega set to 0);
+* ``no_exchange`` (a pod cell): every delta exchange is left out, each
+  pod keeping its own parameters.
 """
 import os
 import sys
@@ -39,6 +43,19 @@ def plant(fault, cell):
             return loss(params, {k: v[:v.shape[0] // 2]
                                  for k, v in batch.items()})
         model.loss = half_loss
+    elif fault in ("drop_pod", "no_exchange"):
+        inner = trainer.step
+
+        def step(state, batch, plan, kind="grad_sync"):
+            if kind != "delta_sync":
+                return inner(state, batch, plan, kind)
+            if fault == "no_exchange":
+                return state, {}
+            omega = list(plan.omega)
+            omega[-1] = 0.0
+            return inner(state, batch,
+                         trainer.exec_plan(plan).with_omega(omega), kind)
+        trainer.step = step
     else:
         raise ValueError(f"unknown fault {fault!r}")
 

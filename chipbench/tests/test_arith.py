@@ -10,28 +10,30 @@ import pytest
 
 import arith
 import run
-from conftest import BENCH
+from conftest import BENCH, ROOT
 
-CONFIGS = ("paper-350m",)
+CONFIGS = tuple(c["name"] for c in json.load(
+    open(os.path.join(ROOT, "BENCHMARK.json")))["configs"])
 
 
-def load_cfg(name):
+def load_cfg(name, rehearsal=False):
     with open(os.path.join(BENCH, "configs", name + ".json")) as f:
-        return json.load(f)
+        cfg = json.load(f)
+    return dict(cfg, **cfg["rehearsal"]) if rehearsal else cfg
 
 
 def program_model(cfg):
-    from repro.configs.base import ModelConfig
     from repro.models.registry import build_model
-    return build_model(ModelConfig(name=cfg["name"],
-                                   **{k: cfg[k] for k in run.MODEL_KEYS}))
+    return build_model(run.model_config(cfg))
 
 
+@pytest.mark.parametrize("rehearsal", [False, True])
 @pytest.mark.parametrize("name", CONFIGS)
-def test_param_count_matches_program_specs(name):
-    """N is the program's parameter count less the embedding's padding
-    rows, which no token ever reads."""
-    cfg = load_cfg(name)
+def test_param_count_matches_program_specs(name, rehearsal):
+    """N, as the configuration's family counts it, is the program's
+    parameter count less the embedding's padding rows, which no token
+    ever reads: at the cell's sizes and at the rehearsal's."""
+    cfg = load_cfg(name, rehearsal)
     specs = program_model(cfg).param_specs()
     total = sum(math.prod(s.shape) for s in jax.tree.leaves(specs))
     pad_rows = cfg["embedding_rows"] - cfg["vocab_size"]

@@ -18,6 +18,19 @@ CELLS = [w["name"] for w in
 SEED = 3_000_000_019          # beyond 32 signed bits
 
 
+def pods(cell):
+    """The pods of a cell's traffic (one chip each)."""
+    return run.pods_of(run.load_cell(cell, rehearsal=True)[3])
+
+
+def faults(cell):
+    """The faults a cell can have, planted in the program: every cell's
+    step can leave its state unchanged or drop half its batch; a pod
+    cell's exchange can drop one pod's delta or be left out."""
+    out = ["unchanged", "half_batch"]
+    return out + (["drop_pod", "no_exchange"] if pods(cell) > 1 else [])
+
+
 def harness(args, *, rehearsal=True, script=None, cwd=ROOT):
     env = dict(os.environ, JAX_PLATFORMS="cpu")
     env.pop("XLA_FLAGS", None)
@@ -49,10 +62,13 @@ def test_rehearsal_of_each_cell(cell):
     # a CPU run names no device metric
     assert "metrics" not in res and "device" not in res
     assert list(res)[-1] == "checks"
+    if pods(cell) > 1:
+        # the fixed-point fold gives every pod the same bits
+        assert res["checks"]["pod_spread"]["value"] == 0.0
 
 
 @pytest.mark.parametrize("cell,fault", [(c, f) for c in CELLS
-                                        for f in ("unchanged", "half_batch")])
+                                        for f in faults(c)])
 def test_broken_program_is_not_correct(cell, fault):
     proc = harness([fault] + cell_args(cell, SEED + 1),
                    script=os.path.join(HERE, "broken_run.py"))

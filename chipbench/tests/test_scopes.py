@@ -58,18 +58,20 @@ def test_phase_seconds_sums_self_time_per_phase():
              "fusion.1": "jit(s)/transpose(jvp(forward))/while/body/mul",
              "fusion.2": "jit(s)/exchange/encode_INT8/mul",
              "fusion.3": "jit(s)/optimizer/add"}
-    per, parts = scopes.phase_seconds(evs, names, 0.0, 12.0)
+    per, parts, coll = scopes.phase_seconds(evs, names, 0.0, 12.0)
+    assert coll == 0.0
     assert per == {"backward": pytest.approx(3.0 + 2.0),
                    "exchange": pytest.approx(5.0),
                    "optimizer": pytest.approx(2.0)}
     assert parts == {"encode_INT8": pytest.approx(5.0)}
     # clipped to the window: the while's self time scales with its share
-    per, _ = scopes.phase_seconds(evs, names, 0.0, 5.0)
+    per, _, _ = scopes.phase_seconds(evs, names, 0.0, 5.0)
     assert per["backward"] == pytest.approx(3.0 * 0.5 + 2.0)
     assert per["exchange"] == pytest.approx(5.0 * 0.2)
     assert "optimizer" not in per
     # an instruction the module does not name is unscoped
-    per, _ = scopes.phase_seconds([_ev("copy.9", 0.0, 1.0)], names, 0.0, 2.0)
+    per, _, _ = scopes.phase_seconds([_ev("copy.9", 0.0, 1.0)], names,
+                                     0.0, 2.0)
     assert per == {"unscoped": pytest.approx(1.0)}
 
 
@@ -92,6 +94,99 @@ def test_only_the_step_modules_ops_count():
     assert red["module"] == "jit_step(1)"
     assert red["phases"] == {"forward": pytest.approx(1.0),
                              "optimizer": pytest.approx(8.0)}
+
+
+def _coll(name, op, s, e):
+    return (f"%{name} = bf16[8]{{0}} {op}(bf16[8]{{0}} %p)", s, e)
+
+
+def _pod_trace():
+    """Two chips, each running a local step module and a delta step
+    module compiled from the same function, and a small module of
+    another; the delta step holds an all-reduce and a collective-permute
+    (4 ms and 2 ms on chip 0, 6 and 4 on chip 1), the local step none."""
+    trace, names = {"modules": {}, "ops": {}}, {}
+    for chip, (ar, cp) in enumerate([(4.0, 2.0), (6.0, 4.0)]):
+        plane = f"/device:TPU:{chip}"
+        trace["modules"][plane] = [
+            ("jit_wrapped(1)", 0.0, 100.0), ("jit_wrapped(2)", 100.0, 130.0),
+            ("jit_other(3)", 130.0, 131.0), ("jit_wrapped(1)", 131.0, 231.0)]
+        trace["ops"][plane] = [
+            _ev("fusion.1", 1.0, 90.0),
+            _ev("fusion.2", 101.0, 110.0),
+            _coll("all-reduce-start.1", "all-reduce-start", 110.0,
+                  110.0 + ar),
+            _coll("collective-permute.3", "collective-permute", 120.0,
+                  120.0 + cp),
+            _coll("all-reduce.9", "all-reduce", 130.2, 130.8),
+            _ev("fusion.1", 132.0, 221.0)]
+    names["jit_wrapped(1)"] = {"fusion.1": "jit(w)/jvp(forward)/mul"}
+    names["jit_wrapped(2)"] = {
+        "fusion.2": "jit(w)/exchange/encode_INT4/mul",
+        "all-reduce-start.1": "jit(w)/exchange/encode_FULL/psum",
+        "collective-permute.3": "jit(w)/exchange/encode_INT4/ppermute"}
+    names["jit_other(3)"] = {"all-reduce.9": "jit(o)/psum"}
+    trace["hlo"] = names
+    trace["spans"] = []
+    return trace
+
+
+def test_step_modules_are_every_kind_of_the_step():
+    tr = _pod_trace()
+    assert scopes.step_modules(tr["modules"], 0.0, 231.0) == [
+        "jit_wrapped(1)", "jit_wrapped(2)"]
+    red = scopes.reduce(tr, 0.0, 231.0)
+    assert red["phases"]["forward"] == pytest.approx(2 * 89.0)
+    # the delta step's exchange, the collectives included, mean of chips
+    assert red["phases"]["exchange"] == pytest.approx(9.0 + 5.0 + 3.0)
+    assert red["collective"] == pytest.approx((4.0 + 2.0 + 6.0 + 4.0) / 2)
+
+
+def _pod_ctx(tr, monkeypatch, sync_steps):
+    class Spans:
+        rec = {}
+
+    monkeypatch.setattr(scopes, "_CACHE", {})
+    monkeypatch.setattr(scopes, "window_trace", lambda t0: "pod")
+    monkeypatch.setattr(scopes, "load", lambda path: tr)
+    monkeypatch.setattr(tracereduce, "clock_offset", lambda a, b: 0.0)
+    return {"t0": 0.0, "t_end": 231.0, "host_steps": 8,
+            "sync_steps": sync_steps, "spans": Spans()}
+
+
+def test_collective_ms_per_exchanging_step_averaged_over_chips(
+        monkeypatch):
+    import loader
+    read = loader.reader("collective_ms_per_step")
+    ctx = _pod_ctx(_pod_trace(), monkeypatch, sync_steps=2)
+    # 8 s of collectives a chip over two exchanging steps
+    assert read(ctx) == pytest.approx(1e3 * 8.0 / 2)
+
+
+def test_collective_ms_per_step_without_collectives_reads_nothing(
+        monkeypatch):
+    import loader
+    tr = _pod_trace()
+    for plane, evs in tr["ops"].items():
+        tr["ops"][plane] = [e for e in evs if "fusion" in e[0]]
+    read = loader.reader("collective_ms_per_step")
+    assert read(_pod_ctx(tr, monkeypatch, sync_steps=2)) is None
+    # nor does a window with no exchanging step
+    assert read(_pod_ctx(_pod_trace(), monkeypatch, sync_steps=0)) is None
+
+
+@pytest.mark.parametrize("hlo,moves", [
+    ("%all-gather-start.2 = (u8[4]) all-gather-start(u8[1] %p)", True),
+    ("%collective-permute-done.1 = u8[9] collective-permute-done(%p)",
+     True),
+    ("%reduce-scatter.4 = f32[8] reduce-scatter(f32[32] %p)", True),
+    ("%all-to-all.1 = f32[8] all-to-all(f32[8] %p)", True),
+    ("%all-reduce-scatter-fusion.1 = f32[8] fusion(f32[32] %p)", True),
+    ("%fusion.3 = f32[8] fusion(f32[8] %p), kind=kLoop", False),
+    ("%copy.2 = f32[8] copy(f32[8] %all-gather.1)", False),
+])
+def test_collective_ops_by_opcode_or_name(hlo, moves):
+    assert scopes.is_collective(hlo) is moves
 
 
 # -- a tiny protobuf writer for synthetic HLO protos -----------------------

@@ -149,15 +149,15 @@ def test_chip_trace_pallas_calls_and_their_work(chip_trace):
 
 
 def test_chip_trace_per_layer_readers(chip_trace):
-    import run
+    import loader
     _, red = chip_trace
     ctx = {"trace": red, "sync_steps": 16,
            "peaks": arith.peaks_for("TPU v5 lite")}
-    ms = run.load_reader("exchange_kernel_ms_per_step")(ctx)
+    ms = loader.reader("exchange_kernel_ms_per_step")(ctx)
     assert ms == pytest.approx(1e3 * (0.458206 + 0.228893) / 16, rel=1e-4)
-    share = run.load_reader("kernel_roofline")(ctx)
+    share = loader.reader("kernel_roofline")(ctx)
     assert 0 < share < 100
-    assert run.load_reader("idle_share")(ctx) == pytest.approx(
+    assert loader.reader("idle_share")(ctx) == pytest.approx(
         100 * (1 - 4.183955 / red["window_s"]), rel=1e-4)
 
 
@@ -178,7 +178,7 @@ def test_chip_trace_gap_labelled_by_the_host(chip_trace):
 def test_non_codec_pallas_call_is_left_out(chip_trace):
     """A Pallas call that is no codec kernel (an attention kernel, say)
     is charged to neither codec reader."""
-    import run
+    import loader
     _, red = chip_trace
     other = {"hlo": "%flash_attention.4 = bf16[8,16,512,64]{3,2,1,0} "
                     "custom-call(bf16[8,16,512,64]{3,2,1,0} %q), "
@@ -189,5 +189,5 @@ def test_non_codec_pallas_call_is_left_out(chip_trace):
     with_other = dict(ctx, trace=dict(
         red, pallas=dict(red["pallas"], **{"flash_attention.4": other})))
     for name in ("exchange_kernel_ms_per_step", "kernel_roofline"):
-        read = run.load_reader(name)
+        read = loader.reader(name)
         assert read(with_other) == pytest.approx(read(ctx), rel=1e-12)
