@@ -206,6 +206,8 @@ class Reference:
             return sum(terms[1:], terms[0])
         self._fold = jax.jit(fold, static_argnames=("kind",))
         self._add = jax.jit(jnp.add)
+        self._norms = jax.jit(lambda xs: [jnp.sqrt(jnp.sum(jnp.square(x)))
+                                          for x in xs])
         self._sq = jax.jit(lambda t: sum(jnp.sum(jnp.square(x))
                                          for x in jax.tree.leaves(t)))
         self._change = jax.jit(lambda p, k: [
@@ -270,8 +272,9 @@ class Reference:
         replica ``p`` taking the ``p``-th equal share of the rows; ``key``
         the weights' PRNG key.  Returns per-step losses and pre-clip
         gradient norms (each the mean over replicas), and per leaf of
-        replica 0 the norm of the first gradient the optimizer received
-        and of the parameters' change over ``n_steps``."""
+        replica 0 the norm of the first gradient the optimizer received,
+        of the parameters' change over ``n_steps`` and of the
+        error-feedback residual after them."""
         P, devs = self.pods, self.devices
         params, tdef = [], None
         for dev in devs:
@@ -328,5 +331,6 @@ class Reference:
         change = [float(x) for x in self._change(
             jax.tree.unflatten(tdef, params[0]),
             jax.device_put(key, devs[0]))]
+        resid = [float(x) for x in self._norms(err[0])]
         return {"losses": losses, "grad_norms": gnorms, "first": first,
-                "change": change}
+                "change": change, "resid": resid}

@@ -358,12 +358,17 @@ class Cell:
         self.feed = Feed(self.sess.pipeline, self.spans)
         self.completions = Completions()
         self.kinds = []                 # (host step, step kind) per call
+        # (kind, plan id) -> the latest such call's (host step, kind, plan,
+        # state, batch): the state as passed, donated, keeps its avals
+        self.calls = {}
         step = self.spans.wrap("dispatch", trainer.step)
 
         def timed_step(state, batch, plan, kind="grad_sync"):
             out = step(state, batch, plan, kind)
             self.completions.put(self.feed.count, out[1])
             self.kinds.append((self.feed.count, kind))
+            self.calls[(kind, id(plan))] = (self.feed.count, kind, plan,
+                                            state, batch)
             return out
 
         trainer.step = timed_step
@@ -388,8 +393,8 @@ class Cell:
         """Drive the first ``n`` host steps through ``run_steps`` and read
         the losses and pre-clip gradient norms (each the mean over pods),
         pod 0's first gradient as the optimizer got it (from its first
-        moment) and its parameters' change, and the pods' spread after
-        the last of them."""
+        moment), its parameters' change and its error-feedback residual,
+        and the pods' spread after the last of them."""
         beta1 = self.traffic["optimizer"]["beta1"]
         state = self.state
         self.state = None
@@ -403,6 +408,7 @@ class Cell:
         out = {"losses": [h["loss"] for h in hist],
                "grad_norms": [h["grad_norm"] for h in hist],
                "first": first, "change": [float(x) for x in change],
+               "resid": self.leaf_norms(state["ace"].errors),
                "pod_spread": (float(self.spread(state["params"]))
                               if self.pods > 1 else 0.0)}
         self.state = state
@@ -456,8 +462,11 @@ def worst_leaf(prog, ref, keep=None) -> float:
 
 def compare(prog: dict, ref: dict) -> dict:
     """The numbers compared: each step's loss, each step's pre-clip
-    gradient norm, and the first gradient as the optimizer got it and the
-    parameters' change over the first steps (both by the worst leaf)."""
+    gradient norm, and, by the worst leaf, the first gradient as the
+    optimizer got it, the parameters' change over the first steps and the
+    error-feedback residual after them (what the last exchange left
+    uncoded: the exchange moves it at first order where the change sees
+    it at second)."""
     first_ref = ref["first"]
     med = sorted(first_ref)[len(first_ref) // 2]
     # leaves whose reference gradient is nought to rounding move under
@@ -470,6 +479,7 @@ def compare(prog: dict, ref: dict) -> dict:
                          zip(prog["grad_norms"], ref["grad_norms"])),
         "grad_gap": worst_leaf(prog["first"], first_ref),
         "change_gap": worst_leaf(prog["change"], ref["change"], keep),
+        "resid_gap": worst_leaf(prog["resid"], ref["resid"], keep),
     }
 
 
@@ -497,6 +507,70 @@ def reference_readings(cfg, traffic, data_seed, weight_key, rungs, omega,
 
 def judge(numbers: dict, limits: dict) -> bool:
     return all(numbers[k] <= limits[k] for k in limits)
+
+
+# ---------------------------------------------------------------------------
+# the step's memory as the compiler plans it
+# ---------------------------------------------------------------------------
+
+
+def dispatch_spec(x):
+    """A step argument's abstract value as the jitted step saw it at
+    dispatch: shape, dtype, weak type, and the sharding of an array
+    committed to one (a donated array keeps all four)."""
+    import jax
+    import numpy as np
+    committed = getattr(x, "committed", False)
+    return jax.ShapeDtypeStruct(np.shape(x), x.dtype,
+                                weak_type=getattr(x, "weak_type", False),
+                                sharding=x.sharding if committed else None)
+
+
+def step_memory(trainer, calls) -> dict:
+    """The compiler's memory plan, per chip, of the step executable with
+    the largest peak among those ``calls`` ran (``(kind, plan, state,
+    batch)``, a call of each): ``peak_bytes`` (``peak_memory_in_bytes``:
+    the arguments, the outputs not aliased to them and the temporaries
+    live at the peak) and ``argument_bytes`` (train state, batch and
+    plan), with each kind's two under ``by_kind``, and ``compiled``: the
+    executables the backend compiled for the reading.  Each is the
+    trainer's jitted step lowered for the arguments as dispatched, which
+    the process's own cache or the persistent one already holds, so
+    ``compiled`` is 0."""
+    import jax
+    compiles, hits = [], []
+
+    def on_compile(event, _secs, **_):
+        if event == "/jax/core/compile/backend_compile_duration":
+            compiles.append(event)
+
+    def on_hit(event, **_):
+        if event == "/jax/compilation_cache/cache_hits":
+            hits.append(event)
+    jax.monitoring.register_event_duration_secs_listener(on_compile)
+    jax.monitoring.register_event_listener(on_hit)
+    best, seen, by_kind = None, set(), {}
+    try:
+        for kind, plan, state, batch in calls:
+            ep = trainer.exec_plan(plan)
+            key = (ep.static_key(), kind)
+            if key in seen:
+                continue
+            seen.add(key)
+            specs = jax.tree.map(dispatch_spec, (state, batch, ep))
+            mem = trainer.jit_step(ep, kind).lower(
+                *specs).compile().memory_analysis()
+            reading = {"peak_bytes": int(mem.peak_memory_in_bytes),
+                       "argument_bytes": int(mem.argument_size_in_bytes)}
+            by_kind[kind] = reading
+            if best is None or reading["peak_bytes"] > best["peak_bytes"]:
+                best = reading
+    finally:
+        jax.monitoring.unregister_event_duration_listener(on_compile)
+        jax.monitoring.unregister_event_listener(on_hit)
+    if best is None:
+        raise RuntimeError("the window ran no step")
+    return dict(best, by_kind=by_kind, compiled=len(compiles) - len(hits))
 
 
 # ---------------------------------------------------------------------------
@@ -630,6 +704,13 @@ def main(argv=None) -> int:
     sync_steps = sum(1 for s, k in bench_cell.kinds
                      if s >= first_window_step
                      and k in ("grad_sync", "delta_sync"))
+    t_mem = time.perf_counter()
+    memory = step_memory(loop.trainer, [
+        call[1:] for call in bench_cell.calls.values()
+        if call[0] >= first_window_step])
+    log(f"[memory] the step's plan per chip: {memory['by_kind']} "
+        f"compiled={memory['compiled']} "
+        f"read_s={time.perf_counter() - t_mem!r}")
     spans = bench_cell.spans
     sess = bench_cell.sess
 
@@ -662,6 +743,8 @@ def main(argv=None) -> int:
             "step_ms_p95": {"value": 1e3 * percentile(intervals, 95),
                             "unit": "ms"},
             "peak_hbm_gib": {"value": peak / 2 ** 30, "unit": "GiB"},
+            "step_hbm_gib": {"value": memory["peak_bytes"] / 2 ** 30,
+                             "unit": "GiB"},
             "setup_s": {"value": setup_s, "unit": "s"},
         }
         result["device"] = device
@@ -674,7 +757,7 @@ def main(argv=None) -> int:
                "host_steps": host_steps, "window_s": window_s,
                "tokens": tokens, "spans": spans, "t0": t0, "t_end": t_end,
                "trace": red, "peaks": arith.peaks_for(d0.device_kind),
-               "sync_steps": sync_steps}
+               "sync_steps": sync_steps, "step_memory": memory}
         result["metrics"] = per_layer(bench, args.workload, ctx)
         device["busy_s"] = red["busy_s"]
         device["window_s"] = red["window_s"]

@@ -11,8 +11,8 @@ before its first step:
   its mean over those;
 * ``drop_pod`` (a pod cell): the last pod's delta is left out of every
   delta exchange's aggregate (its omega set to 0);
-* ``no_exchange`` (a pod cell): every delta exchange is left out, each
-  pod keeping its own parameters.
+* ``no_exchange`` (a cell with delta exchanges): every delta exchange
+  is left out, each pod keeping its own parameters and residual.
 """
 import os
 import sys

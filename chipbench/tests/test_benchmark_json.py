@@ -85,3 +85,43 @@ def test_metrics(bench):
         assert m["better"] in ("lower", "higher")
     for cell in cells:
         assert any(cell in m["workloads"] for m in bench["per_layer"])
+
+
+def test_step_memory_metric_and_its_two_parts(bench):
+    cells = [w["name"] for w in bench["workloads"]]
+    e2e = {m["name"]: m for m in bench["end_to_end"]}
+    step = e2e["step_hbm_gib"]
+    assert (step["unit"], step["better"], step["source"]) == (
+        "GiB", "lower", "host_clock")
+    assert "workloads" not in step          # every cell reports it
+    per_layer = {m["name"]: m for m in bench["per_layer"]}
+    for name in ("state_gib", "transient_gib"):
+        m = per_layer[name]
+        # read from the compiler by the benchmark itself, as the sum
+        assert (m["layer"], m["moves"], m["unit"], m["source"]) == (
+            "device memory", "step_hbm_gib", "GiB", "host_clock")
+        assert m["workloads"] == cells
+
+
+def test_each_cell_reports_what_its_per_layer_metrics_move(bench):
+    e2e = {m["name"]: m for m in bench["end_to_end"]}
+    for m in bench["per_layer"]:
+        moved = e2e[m["moves"]]
+        assert set(m["workloads"]) <= set(moved.get("workloads",
+                                                    m["workloads"]))
+
+
+def test_collectives_are_read_only_in_cells_with_several_chips(bench):
+    chips = {w["name"]: w["chips"] for w in bench["workloads"]}
+    (coll,) = [m for m in bench["per_layer"]
+               if m["name"] == "collective_ms_per_step"]
+    assert all(chips[c] > 1 for c in coll["workloads"])
+
+
+def test_one_pod_h4_traffic_is_the_pod_cells_on_one_pod():
+    def load(name):
+        with open(os.path.join(BENCH, "traffic", name + ".json")) as f:
+            return json.load(f)
+    one, four = load("ace-h4-1pod"), load("ace-h4")
+    assert (one["pods"], four["pods"]) == (1, 4)
+    assert dict(one, pods=4) == four

@@ -10,15 +10,16 @@ import pytest
 
 import run
 from conftest import BENCH, ROOT
-from test_run import CELLS, pods
+from test_run import CELLS, faults as program_faults
 
 SEEDS = "4000000001,4000000002"
 
 
 @pytest.mark.parametrize("cell", CELLS)
 def test_control_and_faults_fail_the_limits(cell):
-    faults = ["half_batch"] + (["drop_pod", "no_exchange"]
-                               if pods(cell) > 1 else [])
+    # the reference can plant every fault of the program's but a state
+    # left unchanged
+    faults = [f for f in program_faults(cell) if f != "unchanged"]
     env = dict(os.environ, JAX_PLATFORMS="cpu", CHIPBENCH_REHEARSAL="1")
     env.pop("XLA_FLAGS", None)
     proc = subprocess.run(

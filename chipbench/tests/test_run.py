@@ -23,12 +23,19 @@ def pods(cell):
     return run.pods_of(run.load_cell(cell, rehearsal=True)[3])
 
 
+def sync_interval(cell):
+    """The host steps between a cell's delta exchanges (1: none)."""
+    return run.load_cell(cell, rehearsal=True)[3]["sync_interval"]
+
+
 def faults(cell):
     """The faults a cell can have, planted in the program: every cell's
-    step can leave its state unchanged or drop half its batch; a pod
-    cell's exchange can drop one pod's delta or be left out."""
+    step can leave its state unchanged or drop half its batch; a cell's
+    delta exchange can be left out, and a pod cell's can drop one pod's
+    delta."""
     out = ["unchanged", "half_batch"]
-    return out + (["drop_pod", "no_exchange"] if pods(cell) > 1 else [])
+    out += ["no_exchange"] if sync_interval(cell) > 1 else []
+    return out + (["drop_pod"] if pods(cell) > 1 else [])
 
 
 def harness(args, *, rehearsal=True, script=None, cwd=ROOT):
@@ -55,12 +62,19 @@ def cell_args(cell, seed=SEED):
 
 @pytest.mark.parametrize("cell", CELLS)
 def test_rehearsal_of_each_cell(cell):
-    res = result_line(harness(cell_args(cell)))
+    proc = harness(cell_args(cell))
+    res = result_line(proc)
     assert res["correct"] is True, res["checks"]
     assert res["rehearsal"] is True
     assert res["attempted"] > 0 and res["failed"] == 0
-    # a CPU run names no device metric
+    # a CPU run names no device metric, memory among them, though the
+    # step's memory plan is read as on the chip
     assert "metrics" not in res and "device" not in res
+    assert "step_hbm_gib" not in proc.stdout
+    assert "[memory] the step's plan per chip" in proc.stderr
+    # the memory read lowers the dispatched steps again, on the pod mesh
+    # too, and finds each in the compilation cache
+    assert " compiled=0 " in proc.stderr
     assert list(res)[-1] == "checks"
     if pods(cell) > 1:
         # the fixed-point fold gives every pod the same bits
